@@ -23,6 +23,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of suites")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     suites = args.only.split(",") if args.only else SUITES
     print("name,us_per_call,derived")
     failures = []

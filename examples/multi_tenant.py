@@ -20,6 +20,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import DType, Schema
 from repro.server import SharkServer
 
@@ -79,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

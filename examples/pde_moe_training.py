@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import SharkSession
 from repro.data import TokenPipeline, synthetic_corpus
@@ -24,6 +25,7 @@ from repro.models import moe as moe_mod
 from repro.training import AdamWConfig, init_opt_state, make_train_step
 from repro.training.pde_moe import MoEReplanner
 
+enable_compile_cache()
 cfg = get_config("phi3.5-moe-42b-a6.6b-smoke")
 sess = SharkSession(num_workers=2, max_threads=2)
 synthetic_corpus(sess, "corpus", cfg.vocab, n_docs=60, mean_doc_len=256)

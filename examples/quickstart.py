@@ -7,7 +7,10 @@ mid-query fault tolerance.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import DType, Schema, SharkSession, col, count, sum_
+
+enable_compile_cache()
 
 sess = SharkSession(num_workers=4, max_threads=4, default_partitions=8)
 rng = np.random.default_rng(0)

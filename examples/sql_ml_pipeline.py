@@ -8,8 +8,11 @@ zero SQL-string plumbing between stages.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import DType, Schema, SharkSession, col
 from repro.ml import KMeans, LogisticRegression
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 n, d = 50_000, 10

@@ -10,9 +10,11 @@ takes --arch qwen2.5-3b and the production mesh.)
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.argv = [sys.argv[0], "--arch", "qwen2.5-3b-smoke",
                 "--steps", "300", "--seq-len", "64", "--batch", "16",
                 "--lr", "3e-3", "--ckpt-every", "100",

@@ -110,13 +110,14 @@ class MeshContext:
     def mesh(self):
         """1-D ('data',) mesh over the alive devices, cached per
         generation (shard_map program caches key off this object)."""
-        from ..parallel import compat
+        import jax
         with self.lock:
             gen = self.generation
             m = self._mesh_cache.get(gen)
             if m is None:
                 devs = [self.devices[i] for i in self.alive_slots()]
-                m = compat.make_mesh((len(devs),), ("data",), devices=devs)
+                m = jax.make_mesh((len(devs),), ("data",), devices=devs,
+                                  axis_types=(jax.sharding.AxisType.Auto,))
                 self._mesh_cache = {gen: m}     # old generations are stale
             return m, gen
 

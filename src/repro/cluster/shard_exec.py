@@ -77,7 +77,6 @@ def _colscan_program(mesh, per: int, rows: int):
     if fn is None:
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local(F, A, N, lo, hi):
@@ -90,7 +89,7 @@ def _colscan_program(mesh, per: int, rows: int):
             mx = jnp.max(jnp.where(mask, A, -jnp.inf), axis=1)
             return jnp.stack([cnt, s, mn, mx], axis=1)
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(P("data"), P("data"), P("data"), P(), P()),
             out_specs=P("data")))
@@ -153,7 +152,6 @@ def _exchange_program(mesh, n_dev: int, rows: int, stride: int,
     if fn is None:
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local(K, V, F):
@@ -185,7 +183,7 @@ def _exchange_program(mesh, n_dev: int, rows: int, stride: int,
                   for x in (outk, outv, outf)]
             return ex[0][None], ex[1][None], ex[2][None]
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(P("data", None),) * 3,
             out_specs=(P("data", None),) * 3))

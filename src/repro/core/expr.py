@@ -488,8 +488,8 @@ class ExprCompileError(Exception):
 
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    import jax
+    return jax.enable_x64()
 
 
 def next_pow2(n: int) -> int:

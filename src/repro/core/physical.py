@@ -627,10 +627,12 @@ class SegmentRunner:
             src = self._source_col(a.arg.name)
             if src is None or self.schema.dtype(src) == DType.STRING:
                 return None
-            if (self.schema.dtype(src) == DType.INT64
+            sdt = self.schema.dtype(src)
+            if (sdt.is_integer and (sdt == DType.INT64 or _on_tpu())
                     and a.func in (AggFunc.SUM, AggFunc.MIN, AggFunc.MAX)):
-                # int64 aggregates keep integer accumulators (exact above
-                # 2^53); the float-accumulating kernel shapes would round
+                # integer aggregates keep integer accumulators: the kernel
+                # shapes accumulate in float and would round int64 past 2^53
+                # (float64, CPU) and any integer past 2^24 (float32, TPU)
                 return None
             if value_col is None:
                 value_col = src
@@ -725,11 +727,6 @@ class SegmentRunner:
                    fused=fused and route != "numpy")
         return out, route
 
-    def _acc_dtype(self) -> str:
-        # float32 is the TPU-native accumulator; CPU interpret mode matches
-        # the numpy oracle to rounding with float64
-        return "float32" if _on_tpu() else "float64"
-
     def _run_colscan(self, batch: PartitionBatch, shape, aggs,
                      pallas: bool) -> Tuple[PartitionBatch, str]:
         from ..kernels import ops as kernel_ops
@@ -743,6 +740,10 @@ class SegmentRunner:
         vals = np.asarray(batch.col(vcol).arr)
         coded = (fv.block is not None and not fv.materialized
                  and fv.block.code_space() is not None)
+        acc = kernel_ops.default_acc_dtype()
+        if pallas and not coded and kernel_ops.colscan_filter(
+                fv.arr, acc) is None:
+            pallas = False  # the kernel cannot compare this filter exactly
         framed = (not coded and self.cfg.compressed_domain
                   and fv.block is not None and not fv.materialized
                   and fv.block.frame_space() is not None)
@@ -753,17 +754,17 @@ class SegmentRunner:
         with _x64():
             if pallas and coded:
                 codes, d = fv.block.code_space()
-                # decode fused into the scan: the filter column is read as
-                # codes, its dictionary gathered inside the kernel
+                # decode fused away: the filter column is read as codes
+                # and compared against code bounds of the sorted dictionary
                 res = self._pallas_colscan_chunked(
                     lambda c, v: kernel_ops.fused_decode_scan(
-                        c, d, v, lo, hi, acc_dtype=self._acc_dtype()),
+                        c, d, v, lo, hi, acc_dtype=acc),
                     np.asarray(codes), vals)
                 route = "fused_decode_scan"
             elif pallas:
                 res = self._pallas_colscan_chunked(
                     lambda f, v: kernel_ops.colscan(
-                        f, v, lo, hi, acc_dtype=self._acc_dtype()),
+                        f, v, lo, hi, acc_dtype=acc),
                     np.asarray(fv.arr), vals)
                 route = "colscan"
             elif coded:
@@ -925,7 +926,8 @@ class SegmentRunner:
         with _x64():
             if kernel:
                 res = np.asarray(kernel_ops.groupby_sum(
-                    codes, vals, num_groups, acc_dtype=self._acc_dtype()))
+                    codes, vals, num_groups,
+                    acc_dtype=kernel_ops.default_acc_dtype()))
                 route = "groupby_mxu"
             else:
                 res = _code_groupby(np.asarray(codes), vals, num_groups)
@@ -1073,7 +1075,7 @@ class ReduceRunner:
                                          _on_tpu(), self.cfg)
         if num_groups == 0 or redecide.route != "segmented_merge":
             return self._merge_jit(batch, group_cols, aggs), "jit"
-        acc = "float32" if _on_tpu() else "float64"
+        acc = kernel_ops.default_acc_dtype()
         lanes: Dict[str, np.ndarray] = {}
         with _x64():
             for spec in aggs:

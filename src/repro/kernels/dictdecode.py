@@ -10,9 +10,14 @@ encoded stream tile-by-tile and materializes decoded tiles only in VMEM.
   * rle_decode: run values + cumulative ends; each output tile computes its
     run index with a broadcasted compare-and-sum against the (VMEM-resident)
     ends vector — O(tile x runs) VPU ops, no serial scan;
-  * fused_decode_scan: dict decode fused directly into the filter+aggregate
-    scan — compressed column in, [count,sum,min,max] out, nothing decoded
-    ever leaves VMEM (the end-to-end point of the paper's §3.2 + §5 story).
+
+The scan over a dict-coded filter column (`ops.fused_decode_scan`) needs no
+decoder at all: the engine's dictionaries are sorted, so value bounds become
+code bounds and the colscan kernel compares the int32 codes directly.
+
+None of these three decoders compiles for the TPU (Mosaic refuses the
+vector gather, the dynamic run-index gather and the (n, per_word) -> (n *
+per_word) reshape), and the engine calls none of them.
 """
 
 from __future__ import annotations
@@ -106,60 +111,3 @@ def rle_decode(run_values: jnp.ndarray, run_ends: jnp.ndarray, *, n: int,
         interpret=interpret,
     )(run_ends.astype(jnp.int32), run_values)
     return out[:n]
-
-
-def _fused_decode_scan_kernel(codes_ref, dict_ref, agg_ref, bounds_ref,
-                              out_ref):
-    dt = out_ref.dtype
-    lo = bounds_ref[0]
-    hi = bounds_ref[1]
-    vals = dict_ref[codes_ref[...]].astype(dt)
-    a = agg_ref[...].astype(dt)
-    mask = (vals >= lo) & (vals <= hi)
-    cnt = jnp.sum(mask.astype(dt))
-    s = jnp.sum(jnp.where(mask, a, 0.0))
-    mn = jnp.min(jnp.where(mask, a, jnp.inf))
-    mx = jnp.max(jnp.where(mask, a, -jnp.inf))
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-    out_ref[...] = jnp.where(lane == 0, cnt,
-                             jnp.where(lane == 1, s,
-                                       jnp.where(lane == 2, mn,
-                                                 jnp.where(lane == 3, mx,
-                                                           0.0)))).astype(dt)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block",
-                                             "acc_dtype"))
-def fused_decode_scan(codes: jnp.ndarray, dictionary: jnp.ndarray,
-                      agg_col: jnp.ndarray, lo, hi, *,
-                      interpret: bool = False, block: int = BLOCK,
-                      acc_dtype: str = "float32") -> jnp.ndarray:
-    """Compressed (dict-coded) filter column + plain aggregate column ->
-    [count, sum, min, max]; decode fused into the scan.  `acc_dtype` is
-    float32 on TPU; the engine passes float64 in CPU interpret mode to
-    match the numpy oracle to rounding."""
-    dt = jnp.dtype(acc_dtype)
-    n = codes.shape[0]
-    d = dictionary.shape[0]
-    num_blocks = max(1, -(-n // block))
-    padded = num_blocks * block
-    # pad codes with a sentinel appended to the dict; NaN fails both bound
-    # comparisons, so padding stays excluded even when lo or hi is ±inf
-    dict_pad = jnp.concatenate([dictionary.astype(dt),
-                                jnp.asarray([jnp.nan], dt)])
-    c = jnp.full((padded,), d, jnp.int32).at[:n].set(codes.astype(jnp.int32))
-    a = jnp.zeros((padded,), dt).at[:n].set(agg_col.astype(dt))
-    bounds = jnp.asarray([lo, hi], dt)
-    partials = pl.pallas_call(
-        _fused_decode_scan_kernel,
-        grid=(num_blocks,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((d + 1,), lambda i: (0,)),
-                  pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((2,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((1, 128), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_blocks, 128), dt),
-        interpret=interpret,
-    )(c, dict_pad, a, bounds)
-    return jnp.stack([jnp.sum(partials[:, 0]), jnp.sum(partials[:, 1]),
-                      jnp.min(partials[:, 2]), jnp.max(partials[:, 3])])

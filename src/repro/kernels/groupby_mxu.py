@@ -9,9 +9,10 @@ country: ~200 — see §6.3.1/§6.4), the TPU-native algorithm is:
     one_hot(codes) @ ones    -> per-group counts
 
 Each grid step builds the one-hot tile for BLOCK_ROWS rows in VMEM and issues
-two fused matmuls; partial (G,2) results land per-tile and the wrapper does
-the final (num_blocks, G, 2) -> (G, 2) sum.  G is padded to a multiple of 128
-so the matmul is MXU-aligned.  High-cardinality group-bys stay on the
+two fused matmuls at full f32 contract precision; partial (G,2) results land
+per-tile and the wrapper does the final (num_blocks, G, 2) -> (G, 2) sum,
+counts in int32 so they stay exact past float32's 2^24.  G is padded to a
+multiple of 128 so the matmul is MXU-aligned.  High-cardinality group-bys stay on the
 sort/segment-sum engine path (aggregate.py).
 """
 
@@ -33,7 +34,8 @@ def _groupby_kernel(codes_ref, vals_ref, out_ref, *, num_groups_padded: int):
     groups = jax.lax.broadcasted_iota(jnp.int32, (1, num_groups_padded), 1)
     onehot = (codes[:, None] == groups).astype(dt)  # (B, Gp)
     stacked = jnp.stack([vals, jnp.ones_like(vals)], axis=0)  # (2, B)
-    out_ref[...] = (stacked @ onehot)[None]  # (1, 2, Gp) on the MXU
+    out_ref[...] = jnp.dot(stacked, onehot,               # MXU: (2, Gp)
+                           precision=jax.lax.Precision.HIGHEST)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "interpret",
@@ -42,8 +44,8 @@ def groupby_sum(codes: jnp.ndarray, values: jnp.ndarray, *, num_groups: int,
                 interpret: bool = False,
                 block_rows: int = BLOCK_ROWS,
                 acc_dtype: str = "float32") -> jnp.ndarray:
-    """Returns (num_groups, 2): per-group [sum, count].  `acc_dtype` is
-    float32 on TPU (MXU-native); the engine passes float64 in interpret
+    """Returns (sums[num_groups], counts[num_groups] int32).  `acc_dtype`
+    is float32 on TPU (MXU-native); the engine passes float64 in interpret
     mode on CPU to match the numpy oracle to rounding."""
     dt = jnp.dtype(acc_dtype)
     n = codes.shape[0]
@@ -62,5 +64,8 @@ def groupby_sum(codes: jnp.ndarray, values: jnp.ndarray, *, num_groups: int,
         out_shape=jax.ShapeDtypeStruct((num_blocks, 2, gp), dt),
         interpret=interpret,
     )(c, v)
-    summed = jnp.sum(partials, axis=0)  # (2, gp)
-    return summed[:, :num_groups].T     # (G, 2) [sum, count]
+    sums = jnp.sum(partials[:, 0, :num_groups], axis=0)
+    # per-tile counts are exact small floats (<= block_rows): cast to int32
+    # BEFORE the cross-tile sum so totals stay exact past 2^24
+    counts = jnp.sum(partials[:, 1, :num_groups].astype(jnp.int32), axis=0)
+    return sums, counts

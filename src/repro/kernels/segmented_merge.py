@@ -37,7 +37,8 @@ def _segmerge_kernel(codes_ref, vals_ref, out_ref, *,
     onehot = codes[:, None] == groups                       # (B, Gp) bool
     oh = onehot.astype(dt)
     stacked = jnp.stack([vals, jnp.ones_like(vals)], axis=0)  # (2, B)
-    sc = stacked @ oh                                       # MXU: (2, Gp)
+    sc = jnp.dot(stacked, oh,                               # MXU: (2, Gp)
+                 precision=jax.lax.Precision.HIGHEST)
     mn = jnp.min(jnp.where(onehot, vals[:, None], jnp.inf), axis=0)
     mx = jnp.max(jnp.where(onehot, vals[:, None], -jnp.inf), axis=0)
     out_ref[...] = jnp.concatenate(

@@ -62,7 +62,8 @@ def _topk_kernel(x_ref, q_ref, out_s_ref, out_i_ref, *, n: int,
 
     x = x_ref[...]                                     # (B, d_pad)
     qv = q_ref[...]                                    # (d_pad, 1)
-    s_tile = (x @ qv).T                                # (1, B) MXU dot
+    s_tile = jnp.dot(x, qv,                            # (1, B) MXU dot
+                     precision=jax.lax.Precision.HIGHEST).T
     gi = (i * block_rows
           + jax.lax.broadcasted_iota(jnp.int32, (1, block_rows),
                                      1)).astype(jnp.int32)

@@ -1,14 +1,18 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell with ShapeDtypeStruct inputs (no allocation), print
 memory_analysis / cost_analysis, and write the roofline record.
 
-The two lines ABOVE the docstring must run before any jax import: jax locks
+The lines ABOVE the docstring must run before any jax import: jax locks
 the device count at first init, and the production meshes need 512 host
-devices.
+devices.  They also pin this process and the `--all` children (which
+inherit the environment) to the CPU platform: the dry run compiles on host
+devices, and on a machine with a TPU every child would otherwise try to
+open the one chip.
 
 Usage:
     python -m repro.launch.dryrun --arch qwen2.5-3b --shape train_4k
@@ -79,8 +83,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     fn, arg_shapes, in_sh, out_sh = build_cell(cfg, shape, mesh,
                                                microbatches=microbatches)
     jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
-    from ..parallel.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jitted.lower(*arg_shapes)
         t_lower = time.time() - t0
         compiled = lowered.compile()

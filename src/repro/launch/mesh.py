@@ -16,7 +16,11 @@ from typing import Optional
 import jax
 import numpy as np
 
-from ..parallel.compat import make_mesh
+
+
+def _make_mesh(shape, axes, devices):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices but only {len(devices)} present;"
             " run under XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n} (launch/dryrun.py does this)")
-    return make_mesh(shape, axes, devices=devices[:n])
+    return _make_mesh(shape, axes, devices[:n])
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: Optional[int] = None):
@@ -39,4 +43,4 @@ def make_debug_mesh(data: int = 2, model: int = 2, pod: Optional[int] = None):
     else:
         shape, axes = (data, model), ("data", "model")
     n = int(np.prod(shape))
-    return make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _make_mesh(shape, axes, jax.devices()[:n])

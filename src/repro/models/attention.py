@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.compat import get_abstract_mesh
 from .common import (Params, Specs, apply_rope, dense_init,
                      stacked_dense_init)
 
@@ -190,8 +189,8 @@ def _decode_q_constraint(qg, n_kv: int, head_dim: int):
     heads don't divide the model axis, caches shard head_dim; constrain q the
     same way so the score contraction runs as local partial dots + a small
     all-reduce instead of GSPMD gathering the cache (perf iteration C3)."""
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return qg
     msize = dict(zip(mesh.axis_names, mesh.axis_sizes))["model"]
     if n_kv % msize == 0 or head_dim % msize != 0:

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.compat import get_abstract_mesh
 from .common import Params, Specs, stacked_dense_init
 
 
@@ -171,8 +170,8 @@ def moe_apply_ep(p: Params, x: jnp.ndarray, cfg: MoEConfig,
 
     x: (B, S, D).  Requires a mesh with a `model` axis whose size divides
     both S and num_experts; falls back to the GSPMD path otherwise."""
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return moe_apply(p, x, cfg, return_stats=return_stats)
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     ep = sizes["model"]
@@ -226,13 +225,12 @@ def moe_apply_ep(p: Params, x: jnp.ndarray, cfg: MoEConfig,
         yf = jnp.zeros((t, d), jnp.float32).at[tok_idx].add(weighted)
         return yf.astype(xb.dtype).reshape(bl, sl, d)
 
-    from jax.experimental.shard_map import shard_map
     x_spec = P(baxes if baxes else None, "model", None)
-    y = shard_map(
+    y = jax.shard_map(
         block, mesh=mesh,
         in_specs=(x_spec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
-        out_specs=x_spec, check_rep=False,
+        out_specs=x_spec, check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if cfg.n_shared > 0:

@@ -19,15 +19,14 @@ from typing import Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .compat import get_abstract_mesh
 
 BATCH_AXES: Tuple[str, ...] = ("pod", "data")
 MODEL_AXIS = "model"
 
 
 def current_mesh_axes() -> Tuple[str, ...]:
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return ()
     return tuple(mesh.axis_names)
 

@@ -16,7 +16,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
-from repro.parallel.compat import set_mesh
 from repro.configs import get_config
 from repro.launch.mesh import make_debug_mesh
 from repro.models import lm
@@ -32,7 +31,7 @@ step = make_train_step(cfg, AdamWConfig(lr=1e-3))
 mesh_a = make_debug_mesh(4, 2)
 params, _ = lm.init_params(cfg, jax.random.PRNGKey(0))
 opt = init_opt_state(params)
-with set_mesh(mesh_a):
+with jax.sharding.set_mesh(mesh_a):
     fa = jax.jit(step)
     for _ in range(3):
         params, opt, m = fa(params, opt, batch)
@@ -44,14 +43,14 @@ with tempfile.TemporaryDirectory() as d:
     # "pod failure": restart on mesh B = (data=2, model=2) — 4 devices
     restored, man = mgr.restore_latest({"params": params, "opt": opt})
     mesh_b = make_debug_mesh(2, 2)
-    with set_mesh(mesh_b):
+    with jax.sharding.set_mesh(mesh_b):
         fb = jax.jit(step)
         p2, o2, m2 = fb(restored["params"], restored["opt"], batch)
     assert int(o2["step"]) == 4
     assert np.isfinite(float(m2["loss"]))
     # and scale UP to mesh C = (data=4, model=2) again
     mesh_c = make_debug_mesh(4, 2)
-    with set_mesh(mesh_c):
+    with jax.sharding.set_mesh(mesh_c):
         fc = jax.jit(step)
         p3, o3, m3 = fc(restored["params"], restored["opt"], batch)
     # same step from the same checkpoint on different meshes: same loss

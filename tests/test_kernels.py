@@ -76,8 +76,9 @@ def test_groupby_sweep(n, g):
 
 
 def test_fused_decode_scan_matches_unfused():
+    # dictionaries are sorted, as ColumnBlock.code_space hands them out
     n, d = 8 * 128 + 9, 300
-    dic = RNG.normal(size=d).astype(np.float32)
+    dic = np.sort(RNG.normal(size=d).astype(np.float32))
     codes = RNG.integers(0, d, n).astype(np.int32)
     agg = RNG.normal(size=n).astype(np.float32)
     got = np.asarray(ops.fused_decode_scan(codes, dic, agg, -0.4, 0.9))

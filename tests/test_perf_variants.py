@@ -106,12 +106,6 @@ def test_kv_int8_decode_close():
             == np.asarray(jnp.argmax(d2[:, 0], -1))).all()
 
 
-@pytest.mark.xfail(
-    reason="known jax-0.4.37 bug: shard_map EP MoE mis-lowers through XLA "
-           "on host-platform debug meshes and diverges from the GSPMD "
-           "reference (pre-existing since the seed; tracked so tier-1 stays "
-           "green and NEW regressions in this test become visible)",
-    strict=False)
 def test_moe_ep_matches_gspmd_subprocess():
     """EP shard_map MoE vs GSPMD MoE on a 8-device debug mesh."""
     code = """
@@ -120,13 +114,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import make_debug_mesh
 from repro.models.moe import MoEConfig, moe_apply, moe_apply_ep, moe_init
-from repro.parallel.compat import set_mesh
 mesh = make_debug_mesh(2, 4)
 cfg = MoEConfig(num_experts=8, top_k=2, d_expert=32, capacity_factor=4.0)
 p, _ = moe_init(jax.random.PRNGKey(0), 64, cfg)
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(4, 16, 64)).astype(np.float32)).astype(jnp.bfloat16)
-with set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     y1 = jax.jit(lambda p, x: moe_apply(p, x, cfg))(p, x)
     y2 = jax.jit(lambda p, x: moe_apply_ep(p, x, cfg))(p, x)
 rel = np.abs(np.asarray(y1, np.float32) - np.asarray(y2, np.float32)).max()

@@ -179,6 +179,21 @@ def test_pipelined_reduce_failure_falls_back_to_pull(monkeypatch):
     sess.shutdown()
 
 
+def test_pipelined_reduce_persistent_failure_raises(monkeypatch):
+    """A reduce that fails every time is not swallowed by the pipelined
+    attempt: the pull path re-runs it and raises the same error."""
+    from repro.core.physical import ReduceRunner
+
+    def boom(self, *a, **kw):
+        raise RuntimeError("injected compiled-reduce failure")
+
+    monkeypatch.setattr(ReduceRunner, "merge", boom)
+    sess, _ = _star_session()
+    with pytest.raises(RuntimeError, match="injected compiled-reduce"):
+        sess.sql_np("SELECT SUM(fv) AS s, COUNT(*) AS c FROM t")
+    sess.shutdown()
+
+
 # ---------------------------------------------------------------------------
 # Double-buffered Pallas dispatch (interpret mode on CPU)
 # ---------------------------------------------------------------------------

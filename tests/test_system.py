@@ -77,13 +77,12 @@ from repro.configs import get_config, SHAPES, ShapeConfig
 from repro.launch.mesh import make_debug_mesh
 from repro.launch.specs import build_cell
 from repro.launch.hlo_analysis import analyze_compiled
-from repro.parallel.compat import set_mesh
 import dataclasses
 cfg = get_config("qwen2.5-3b-smoke")
 mesh = make_debug_mesh(2, 2, pod={2 if multi_pod else None})
 shape = ShapeConfig("t", "train", 64, 8)
 fn, arg_shapes, in_sh, out_sh = build_cell(cfg, shape, mesh)
-with set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     compiled = jax.jit(fn, in_shardings=in_sh,
                        out_shardings=out_sh).lower(*arg_shapes).compile()
 a = analyze_compiled(compiled)
@@ -91,7 +90,7 @@ assert a["roofline"]["flops"] > 0
 assert a["roofline"]["wire_bytes"] > 0, "expected collectives on a mesh"
 sh2 = ShapeConfig("d", "decode", 128, 8)
 fn, arg_shapes, in_sh, out_sh = build_cell(cfg, sh2, mesh)
-with set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     compiled = jax.jit(fn, in_shardings=in_sh,
                        out_shardings=out_sh).lower(*arg_shapes).compile()
 print("SUBPROCESS_OK")
