@@ -193,6 +193,16 @@ class Counters:
             f"{json.dumps(routes, sort_keys=True)}, fallbacks {fb}")
 
 
+def kernel_launches() -> tuple:
+    """(radix_partition calls, double-buffered dispatches) recorded by the
+    last `tracing.recording()` block."""
+    from repro.core import tracing
+    dev = [r.attrs for r in tracing.records() if r.name == "shark.device"]
+    return (sum(a["program"] == "radix_partition" for a in dev),
+            sum(a.get("chunks", 0) for a in dev
+                if a["program"] == "double_buffer"))
+
+
 def run_query(sess, name: str, sql: str, counters: Counters):
     t0 = time.perf_counter()
     got = sess.sql_np(sql)
@@ -205,9 +215,7 @@ def run_query(sess, name: str, sql: str, counters: Counters):
 # -- one chip -----------------------------------------------------------------
 
 def one_chip(seed: int, size: dict, rehearse: bool, pde_config) -> None:
-    from repro.core import DType, Schema, SharkSession
-    from repro.core.shuffle import RADIX_KERNEL_CALLS
-    from repro.kernels.ops import DOUBLE_BUFFER
+    from repro.core import DType, Schema, SharkSession, tracing
     from repro.ml import LogisticRegression
 
     rng = np.random.default_rng(seed)
@@ -243,9 +251,7 @@ def one_chip(seed: int, size: dict, rehearse: bool, pde_config) -> None:
     del uv
 
     counters = Counters()
-    radix0 = RADIX_KERNEL_CALLS["count"]
-    chunks0 = DOUBLE_BUFFER["dispatches"]
-    with phase("queries"):
+    with tracing.recording(), phase("queries"):
         got = run_query(sess, "pavlo-selection", "SELECT pageURL, pageRank "
                         "FROM rankings WHERE pageRank > 1000", counters)
         m = page_rank > 1000        # pageURL is unique: rows are keys
@@ -315,8 +321,9 @@ def one_chip(seed: int, size: dict, rehearse: bool, pde_config) -> None:
         k = cnt > 0
         check_groups("group-language", got, "languageCode",
                      vocab["lang"][k], {"c": cnt[k]}, {"d": s[k] / cnt[k]})
+    radix, chunks = kernel_launches()
 
-    with phase("analytics"):
+    with tracing.recording(), phase("analytics"):
         fcols = [f"f{i}" for i in range(d)]
         frame = sess.sql("SELECT * FROM users WHERE f0 > -1", lazy=True)
         clf = LogisticRegression(dims=d, lr=0.5, iterations=ITERATIONS)
@@ -343,8 +350,8 @@ def one_chip(seed: int, size: dict, rehearse: bool, pde_config) -> None:
         check(err <= W_TOL, f"logistic regression: weights off by {err:.3e}")
     sess.shutdown()
 
-    radix = RADIX_KERNEL_CALLS["count"] - radix0
-    chunks = DOUBLE_BUFFER["dispatches"] - chunks0
+    radix_fit, chunks_fit = kernel_launches()
+    radix, chunks = radix + radix_fit, chunks + chunks_fit
     log(f"routes fired: {json.dumps(counters.routes, sort_keys=True)}, "
         f"radix_partition calls {radix}, double-buffered dispatches "
         f"{chunks}, fallbacks {counters.fallbacks}")

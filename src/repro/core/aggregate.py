@@ -28,6 +28,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import tracing
 from .batch import PartitionBatch
 from .expr import (ColumnVal, Evaluator, ExprCompileError, evaluate,
                    next_pow2 as _next_pow2)
@@ -372,12 +373,14 @@ class CompiledMerge:
 
         sig = self._signature(batch)
         fn = _merge_fn(sig)
-        with _x64():
-            outs = fn(inv, tuple(cols), gp=gp)
+        with tracing.device("merge", inv, cols) as sp:
+            with _x64():
+                outs = fn(inv, tuple(cols), gp=gp)
+            outs = [sp.fetch(o) for o in outs]
 
         out = _group_key_cols(batch, self.group_cols, first)
         for spec, o in zip(self.aggs, outs):
-            arr = np.asarray(o)[:num_groups]
+            arr = o[:num_groups]
             if spec.func == AggFunc.COUNT:
                 arr = arr.astype(np.int64)
             out[spec.out_name] = ColumnVal(arr)

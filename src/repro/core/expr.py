@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from . import tracing
 from .types import DType, Schema, common_dtype
 
 # ---------------------------------------------------------------------------
@@ -961,11 +962,12 @@ class CompiledExprSet:
             else:
                 env[n] = np.asarray(ctx[n].arr)
         consts = tuple(np.asarray(f(ctx)) for f in plan.extractors)
-        with _x64():
-            outs = plan.jitfn(env, consts)
+        with tracing.device("exprset", env, consts) as sp:
+            with _x64():
+                outs = plan.jitfn(env, consts)
+            arrs = [sp.fetch(out) for out in outs]
         results: List[ColumnVal] = []
-        for out, str_col in zip(outs, plan.out_str_cols):
-            arr = np.asarray(out)
+        for arr, str_col in zip(arrs, plan.out_str_cols):
             if str_col is not None:
                 src = ctx[str_col]
                 results.append(ColumnVal(arr, src.sdict, src.sorted_dict))

@@ -30,6 +30,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from . import tracing
 from .batch import PartitionBatch, merge_string_dicts
 from .expr import ColumnVal, next_pow2 as _next_pow2
 
@@ -146,16 +147,17 @@ class CompiledProbe:
         lk[:n_l] = lkeys
         rk = np.full(rp, sentinel, dt)
         rk[:n_r] = rkeys
-        with _x64():
+        with tracing.device("probe", lk, rk) as sp, _x64():
             order, lo, counts = phase1(lk, rk, n_l, n_r)
-            counts = np.asarray(counts)
+            counts = sp.fetch(counts)
             total = int(counts.sum())
             if total == 0:
                 empty = np.zeros(0, np.int64)
                 return empty, empty.copy()
+            sp.put(counts)
             lidx, ridx = phase2(order, lo, counts, _next_pow2(total))
-        return (np.asarray(lidx[:total], dtype=np.int64),
-                np.asarray(ridx[:total], dtype=np.int64))
+            return (sp.fetch(lidx[:total]).astype(np.int64, copy=False),
+                    sp.fetch(ridx[:total]).astype(np.int64, copy=False))
 
 
 _COMPILED_PROBE = CompiledProbe()

@@ -26,6 +26,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import tracing
 from .aggregate import (CompiledMerge, combine_colscan_stats, group_indices,
                         merge_aggregate, partial_aggregate)
 from .batch import PartitionBatch
@@ -64,8 +65,13 @@ class ExecResult:
     metrics: Optional["ExecMetrics"] = None
 
     def to_numpy(self) -> Dict[str, np.ndarray]:
-        merged = PartitionBatch.concat(self.batches)
-        return merged.decoded()
+        with tracing.span("result") as sp:
+            merged = PartitionBatch.concat(self.batches)
+            if sp:
+                strings = sum(v.is_string for v in merged.cols.values())
+                sp.set(rows=merged.num_rows,
+                       string_rows=merged.num_rows * strings)
+            return merged.decoded()
 
     @property
     def num_rows(self) -> int:
@@ -261,6 +267,14 @@ def _fused_colscan_fns():
 
 
 _BITPACK_COLSCAN_JIT: Dict[int, object] = {}
+
+
+def _jit_colscan(fcol: np.ndarray, vals: np.ndarray, lo: float,
+                 hi: float) -> np.ndarray:
+    """[count, sum, min, max] from the fused jit colscan, read back."""
+    with tracing.device("jit_colscan", fcol, vals) as sp:
+        return sp.fetch(_fused_colscan_fns()(fcol, vals, np.float64(lo),
+                                             np.float64(hi)))
 
 
 def _bitpack_colscan_fn(width: int):
@@ -478,6 +492,13 @@ class SegmentRunner:
         (DESIGN.md §14) needs the route to decide whether the host seam
         was kept (numpy oracle) or the output may ship pre-bucketed.
         `fused=True` tallies compiled partitions under fused_routes."""
+        with tracing.span("segment", rows_in=batch.num_rows) as sp:
+            out, route = self._run_routed(batch, fused)
+            sp.set(route=route, rows_out=out.num_rows)
+        return out, route
+
+    def _run_routed(self, batch: PartitionBatch,
+                    fused: bool) -> Tuple[PartitionBatch, str]:
         rows = batch.num_rows
         nbytes = float(batch.nbytes)
         if self.backend == "numpy":
@@ -674,6 +695,17 @@ class SegmentRunner:
         small-partition numpy decision to the jit route (the differential
         grid forces fusion on tiny seeds); empty partitions stay numpy —
         jnp.min/max of a zero-length array is undefined."""
+        with tracing.span("segment", rows_in=batch.num_rows) as sp:
+            out, route = self._aggregate_by_route(batch, group_cols, aggs,
+                                                  fused, force_compiled)
+            sp.set(route=route, rows_out=out.num_rows)
+        return out, route
+
+    def _aggregate_by_route(self, batch: PartitionBatch,
+                            group_cols: Sequence[str],
+                            aggs: Sequence[AggSpec], fused: bool,
+                            force_compiled: bool
+                            ) -> Tuple[PartitionBatch, str]:
         rows = batch.num_rows
         nbytes = float(batch.nbytes)
         if self.backend == "numpy":
@@ -776,9 +808,7 @@ class SegmentRunner:
                 codes, d = fv.block.code_space()
                 clo = float(np.searchsorted(d, lo, side="left"))
                 chi = float(np.searchsorted(d, hi, side="right") - 1)
-                res = _fused_colscan_fns()(codes, vals,
-                                              np.float64(clo),
-                                              np.float64(chi))
+                res = _jit_colscan(codes, vals, clo, chi)
                 route = "jit-colscan"
             elif framed:
                 # frame-of-reference: value bounds translate to CODE bounds
@@ -790,9 +820,7 @@ class SegmentRunner:
                        if math.isfinite(lo) else -np.inf)
                 chi = (float(int(math.floor(hi)) - int(bias))
                        if math.isfinite(hi) else np.inf)
-                res = _fused_colscan_fns()(codes, vals,
-                                              np.float64(clo),
-                                              np.float64(chi))
+                res = _jit_colscan(codes, vals, clo, chi)
                 route = "for-colscan"
             elif packed:
                 # bit-packed: value bounds translate to biased-code bounds
@@ -811,15 +839,14 @@ class SegmentRunner:
                 a = np.asarray(vals, np.float64)
                 if pad:
                     a = np.pad(a, (0, pad))
-                res = _bitpack_colscan_fn(width)(words, a, np.int64(nrows),
-                                                 np.float64(clo),
-                                                 np.float64(chi))
+                with tracing.device("bitpack_colscan", words, a) as sp:
+                    res = sp.fetch(_bitpack_colscan_fn(width)(
+                        words, a, np.int64(nrows), np.float64(clo),
+                        np.float64(chi)))
                 route = "bitpack-colscan"
             else:
-                res = _fused_colscan_fns()(np.asarray(fv.arr), vals,
-                                              np.float64(lo), np.float64(hi))
+                res = _jit_colscan(np.asarray(fv.arr), vals, lo, hi)
                 route = "jit-colscan"
-            res = np.asarray(res)
         cnt, s, mn, mx = (float(res[0]), float(res[1]), float(res[2]),
                           float(res[3]))
         int_sum = np.issubdtype(np.asarray(vals).dtype, np.integer)
@@ -835,7 +862,8 @@ class SegmentRunner:
         chunk = kernel_ops.DOUBLE_BUFFER["chunk_rows"]
         n = len(fcol)
         if n < 2 * chunk:
-            return fn(fcol, vals)
+            with tracing.device("colscan_state") as sp:
+                return sp.fetch(fn(fcol, vals))
         states = kernel_ops.double_buffer_map(
             lambda fv_pair: fn(fv_pair[0], fv_pair[1]),
             [(fcol[i:i + chunk], vals[i:i + chunk])
@@ -1019,12 +1047,21 @@ class ReduceRunner:
 
     def merge(self, batch: PartitionBatch, group_cols: Sequence[str],
               aggs: Sequence[AggSpec]) -> PartitionBatch:
+        with tracing.span("reduce", op="merge",
+                          rows_in=batch.num_rows) as sp:
+            out, route = self._merge_routed(batch, group_cols, aggs)
+            sp.set(route=route, rows_out=out.num_rows)
+        return out
+
+    def _merge_routed(self, batch: PartitionBatch,
+                      group_cols: Sequence[str], aggs: Sequence[AggSpec]
+                      ) -> Tuple[PartitionBatch, str]:
         rows = batch.num_rows
         nbytes = float(batch.nbytes)
         if self.backend == "numpy":
             out = merge_aggregate(batch, group_cols, aggs)
             self._note("numpy", rows, out.num_rows, nbytes)
-            return out
+            return out, "numpy"
         kernel_eligible = ("segmented_merge"
                            if self._kernel_merge_eligible(batch, aggs)
                            else None)
@@ -1041,9 +1078,9 @@ class ReduceRunner:
         except ExprCompileError:
             out = merge_aggregate(batch, group_cols, aggs)
             self._note("numpy", rows, out.num_rows, nbytes, fallback=True)
-            return out
+            return out, "numpy"
         self._note(route, rows, out.num_rows, nbytes)
-        return out
+        return out, route
 
     def _merge_jit(self, batch: PartitionBatch, group_cols, aggs
                    ) -> PartitionBatch:
@@ -1092,27 +1129,37 @@ class ReduceRunner:
 
     def join(self, lbatch: PartitionBatch, rbatch: PartitionBatch,
              lkey: str, rkey: str, how: str) -> PartitionBatch:
+        with tracing.span("reduce", op="join", rows_in=lbatch.num_rows
+                          + rbatch.num_rows) as sp:
+            out, route = self._join_routed(lbatch, rbatch, lkey, rkey, how)
+            sp.set(route=route, rows_out=out.num_rows)
+        return out
+
+    def _join_routed(self, lbatch: PartitionBatch, rbatch: PartitionBatch,
+                     lkey: str, rkey: str, how: str
+                     ) -> Tuple[PartitionBatch, str]:
         rows = lbatch.num_rows + rbatch.num_rows
         nbytes = float(lbatch.nbytes + rbatch.nbytes)
         if self.backend == "numpy":
             out = join_local(lbatch, rbatch, lkey, rkey, how)
             self._note("numpy", rows, out.num_rows, nbytes)
-            return out
+            return out, "numpy"
         decision = decide_reduce_backend(rows, None, None, _on_tpu(),
                                          self.cfg)
         if decision.route == "numpy":
             out = join_local(lbatch, rbatch, lkey, rkey, how)
             self._note("numpy", rows, out.num_rows, nbytes)
-            return out
+            return out, "numpy"
         try:
             out = join_local(lbatch, rbatch, lkey, rkey, how,
                              matcher=compile_probe())
             self._note("jit", rows, out.num_rows, nbytes)
+            return out, "jit"
         except TypeError:
             # non-numeric key layout the probe cannot take: oracle fallback
             out = join_local(lbatch, rbatch, lkey, rkey, how)
             self._note("numpy", rows, out.num_rows, nbytes, fallback=True)
-        return out
+            return out, "numpy"
 
 
 class JoinShuffledRDD(RDD):
@@ -1305,9 +1352,13 @@ class Executor:
         chaos = getattr(self.ctx, "chaos", None)
         trips_before = chaos.trip_count() if chaos is not None else 0
         res_before = dict(self.ctx.scheduler.resilience_counters)
-        plan = optimize(plan, self.catalog)
-        compiled = self._compile(plan)
-        batches = self.ctx.scheduler.run_result_stage(compiled.rdd)
+        with tracing.query():
+            # physical compilation runs the map stages PDE re-plans on:
+            # their `shark.stage` spans nest inside this one
+            with tracing.span("plan"):
+                plan = optimize(plan, self.catalog)
+                compiled = self._compile(plan)
+            batches = self.ctx.scheduler.run_result_stage(compiled.rdd)
         if storage is not None:
             after = storage.stats()
             m = self.metrics

@@ -30,6 +30,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from . import tracing
 from .batch import PartitionBatch
 from .rdd import (RDD, ShuffleDependency, ShuffledRDD, TaskContext)
 from .resilience import (ResiliencePolicy, ShuffleWaitTimeout, WorkerHealth,
@@ -400,20 +401,6 @@ class Scheduler:
             "retries": 0, "backoffs": 0, "app_probes": 0,
             "fast_fails": 0, "reaps": 0}
         self.stage_stats: Dict[int, StageStats] = {}
-        # pipelined-scheduling event log (DESIGN.md §14): monotonically
-        # sequenced (seq, kind, shuffle_id, detail) tuples — the test
-        # probe that reduce tasks observably start before the map stage
-        # drains.  Bounded: trimmed from the front when it grows large.
-        self.stage_events: List[Tuple[int, str, int, Any]] = []
-        self._event_seq = itertools.count()
-
-    def _log_event(self, kind: str, shuffle_id: int, detail: Any = None
-                   ) -> None:
-        with self.lock:
-            self.stage_events.append(
-                (next(self._event_seq), kind, shuffle_id, detail))
-            if len(self.stage_events) > 4096:
-                del self.stage_events[:2048]
 
     # -- cluster membership --------------------------------------------------
 
@@ -452,9 +439,12 @@ class Scheduler:
     # -- generic stage runner with retry + speculation ------------------------
 
     def _run_tasks(self, stage_id: int, splits: Sequence[int],
-                   run_one: Callable[[int, TaskContext], Any]) -> Dict[int, Any]:
+                   run_one: Callable[[int, TaskContext], Any],
+                   shuffle_id: Optional[int] = None) -> Dict[int, Any]:
         """Run one task per split under the ResiliencePolicy; returns
         split -> result.  `run_one` must be deterministic and idempotent.
+        The stage is one `shark.stage` span, each task attempt a
+        `shark.task` span parented to it (DESIGN.md §17).
 
         Failure handling (DESIGN.md §16):
           * retryable infrastructure faults (policy.is_retryable) retry on
@@ -472,6 +462,13 @@ class Scheduler:
             ZERO tasks have completed, the case duration-based speculation
             structurally cannot cover (the seed deadlocked forever here).
         """
+        with tracing.span("stage", stage_id=stage_id, shuffle_id=shuffle_id,
+                          tasks=len(splits)):
+            return self._run_stage_tasks(stage_id, splits, run_one)
+
+    def _run_stage_tasks(self, stage_id: int, splits: Sequence[int],
+                         run_one: Callable[[int, TaskContext], Any]
+                         ) -> Dict[int, Any]:
         policy = self.policy
         results: Dict[int, Any] = {}
         pending: Set[int] = set(splits)
@@ -491,31 +488,39 @@ class Scheduler:
             attempt_counter[split] += 1
             rec = TaskRecord(split, tc.attempt, worker, time.monotonic(),
                              speculative=speculative)
+            # the stage span, carried to the pool thread with the body
+            cause = tracing.current()
+            submitted = time.perf_counter()
 
             def body():
-                if self.task_launch_overhead_s:
-                    time.sleep(self.task_launch_overhead_s)
-                with self.lock:
-                    if worker not in self.alive:
-                        raise WorkerLost(f"worker {worker} is dead")
-                chaos = getattr(self.ctx, "chaos", None)
-                if chaos is not None:
-                    trip = chaos.fire("task.body")
-                    if trip is not None:
-                        # chaos worker death: the node vanishes (all its
-                        # blocks drop) and a fresh one joins — the exact
-                        # surface the hand-rolled chaos tests poked
-                        self.kill_worker(worker)
-                        self.add_worker()
-                        raise WorkerLost(
-                            f"worker {worker} killed by chaos "
-                            f"({trip.site}#{trip.ordinal})")
-                out = run_one(split, tc)
-                with self.lock:
-                    if worker not in self.alive:
-                        # results computed on a dead worker are discarded
-                        raise WorkerLost(f"worker {worker} died mid-task")
-                return out
+                with tracing.span("task", parent=cause, split=split,
+                                  attempt=tc.attempt, worker=worker,
+                                  queued_s=time.perf_counter() - submitted,
+                                  speculative=speculative):
+                    if self.task_launch_overhead_s:
+                        time.sleep(self.task_launch_overhead_s)
+                    with self.lock:
+                        if worker not in self.alive:
+                            raise WorkerLost(f"worker {worker} is dead")
+                    chaos = getattr(self.ctx, "chaos", None)
+                    if chaos is not None:
+                        trip = chaos.fire("task.body")
+                        if trip is not None:
+                            # chaos worker death: the node vanishes (all its
+                            # blocks drop) and a fresh one joins — the exact
+                            # surface the hand-rolled chaos tests poked
+                            self.kill_worker(worker)
+                            self.add_worker()
+                            raise WorkerLost(
+                                f"worker {worker} killed by chaos "
+                                f"({trip.site}#{trip.ordinal})")
+                    out = run_one(split, tc)
+                    with self.lock:
+                        if worker not in self.alive:
+                            # results computed on a dead worker are discarded
+                            raise WorkerLost(
+                                f"worker {worker} died mid-task")
+                    return out
 
             with self.lock:
                 self.tasks_launched += 1
@@ -720,14 +725,16 @@ class Scheduler:
                     acc.update(b, piece)
                 self.ctx.block_manager.put_shuffle(
                     dep.shuffle_id, split, b, piece, tc.worker_id)
-            self._log_event("map-done", dep.shuffle_id, split)
+            tracing.event("stage.map-done", shuffle_id=dep.shuffle_id,
+                          split=split)
             ts = TaskStats(split, stage_id,
                            {a.name: a.payload() for a in accs})
             with stats_lock:
                 stats.add(ts)
             return True
 
-        self._run_tasks(stage_id, range(parent.num_partitions), run_one)
+        self._run_tasks(stage_id, range(parent.num_partitions), run_one,
+                        shuffle_id=dep.shuffle_id)
         self.stage_stats[stage_id] = stats
         return stats
 
@@ -747,7 +754,7 @@ class Scheduler:
 
         with self.lock:
             self.tasks_recomputed += len(missing)
-        self._run_tasks(stage_id, missing, run_one)
+        self._run_tasks(stage_id, missing, run_one, shuffle_id=dep.shuffle_id)
 
     # -- pipelined map→reduce overlap (DESIGN.md §14) -------------------------
 
@@ -774,7 +781,7 @@ class Scheduler:
         rlock = threading.Lock()
         threads = [
             threading.Thread(
-                target=self._pipelined_reduce,
+                target=tracing.carry(self._pipelined_reduce),
                 args=(dep, r, list(buckets), reduce_fn, done, results, rlock),
                 daemon=True)
             for r, buckets in enumerate(groups)]
@@ -805,14 +812,17 @@ class Scheduler:
                 pieces.extend(bm.fetch_shuffle(
                     dep.shuffle_id, num_maps, buckets, maps=[m]))
                 if m == 0:
-                    self._log_event("reduce-fetch", dep.shuffle_id, split)
-            self._log_event("reduce-start", dep.shuffle_id, split)
+                    tracing.event("stage.reduce-fetch",
+                                  shuffle_id=dep.shuffle_id, split=split)
+            tracing.event("stage.reduce-start", shuffle_id=dep.shuffle_id,
+                          split=split)
             out = reduce_fn(split, pieces)
         except Exception:
             return  # fall back to the pull path (deterministic parity)
         with rlock:
             results[split] = out
-        self._log_event("reduce-done", dep.shuffle_id, split)
+        tracing.event("stage.reduce-done", shuffle_id=dep.shuffle_id,
+                      split=split)
 
     # -- result stages --------------------------------------------------------
 

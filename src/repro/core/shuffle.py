@@ -23,11 +23,9 @@ from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
+from . import tracing
 from .batch import PartitionBatch
 from .columnar import hash_key_values
-
-# diagnostic: how many partitioner calls took the Pallas radix route
-RADIX_KERNEL_CALLS = {"count": 0}
 
 # Dictionaries are immutable load-time state, so their per-entry crc32
 # hashes are derived metadata worth memoizing (the same partition
@@ -73,7 +71,6 @@ def _mix_mod(k: np.ndarray, num_buckets: int) -> np.ndarray:
 def _kernel_buckets(k: np.ndarray, num_buckets: int) -> np.ndarray:
     from ..kernels import ops as kernel_ops
     from ..kernels.radix_partition import fold_keys_u32
-    RADIX_KERNEL_CALLS["count"] += 1
     chunk = kernel_ops.DOUBLE_BUFFER["chunk_rows"]
     if len(k) >= 2 * chunk:
         # Double-buffered: fold+dispatch of chunk i+1 overlaps compute of
@@ -85,9 +82,10 @@ def _kernel_buckets(k: np.ndarray, num_buckets: int) -> np.ndarray:
                 with_counts=False)[0],
             [k[i:i + chunk] for i in range(0, len(k), chunk)])
         return np.concatenate([np.asarray(p) for p in parts])
-    buckets, _ = kernel_ops.radix_partition(
-        fold_keys_u32(k), num_buckets=num_buckets, with_counts=False)
-    return np.asarray(buckets)
+    with tracing.device("radix_buckets") as sp:
+        buckets, _ = kernel_ops.radix_partition(
+            fold_keys_u32(k), num_buckets=num_buckets, with_counts=False)
+        return sp.fetch(buckets)
 
 
 def bucket_by_hash(key: str, num_buckets: int, kernel: bool = False

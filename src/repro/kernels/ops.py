@@ -11,6 +11,11 @@ Every kernel call traces with x64 on only when it accumulates in float64
 (`kernel_x64`), whatever scope the caller holds: under x64 Mosaic refuses
 the 64-bit index maps and constants of a float32 kernel, so the engine's
 `expr._x64()` scopes never reach a kernel on the chip.
+
+Each kernel the engine routes to runs in a `shark.device` span (DESIGN.md
+§17) counting the host arrays it hands the kernel and the results it reads
+back here; a wrapper that returns a device array leaves the read-back to
+its caller's span.  (The decode kernels have no engine caller.)
 """
 
 from __future__ import annotations
@@ -28,6 +33,7 @@ from . import radix_partition as _rp
 from . import segmented_merge as _sm
 from . import topk_similarity as _tk
 from . import train_grad as _tg
+from ..core import tracing
 
 
 @functools.lru_cache(maxsize=1)
@@ -75,9 +81,10 @@ def colscan(filter_col, agg_col, lo, hi, acc_dtype: str = "float32"):
                          f"{np.asarray(filter_col).dtype} filter exactly")
     if f.dtype == np.int32:
         lo, hi = _colscan.int_bounds(lo, hi)
-    with kernel_x64(acc_dtype):
-        return _colscan.colscan(f, np.asarray(agg_col, acc_dtype), lo, hi,
-                                interpret=_interp(), acc_dtype=acc_dtype)
+    a = np.asarray(agg_col, acc_dtype)
+    with tracing.device("colscan", f, a), kernel_x64(acc_dtype):
+        return _colscan.colscan(f, a, lo, hi, interpret=_interp(),
+                                acc_dtype=acc_dtype)
 
 
 def fused_decode_scan(codes, dictionary, agg_col, lo, hi,
@@ -110,23 +117,25 @@ def rle_decode(run_values, run_ends, n: int):
 def groupby_sum(codes, values, num_groups: int, acc_dtype: str = "float32"):
     """(num_groups, 2) float64 per-group [sum, count] via MXU one-hot
     matmul; counts are exact."""
-    with kernel_x64(acc_dtype):
-        s, c = _gb.groupby_sum(np.asarray(codes, np.int32),
-                               np.asarray(values, acc_dtype),
-                               num_groups=num_groups, interpret=_interp(),
-                               acc_dtype=acc_dtype)
-    return np.stack([np.asarray(s, np.float64), np.asarray(c, np.float64)],
-                    axis=1)
+    codes, values = np.asarray(codes, np.int32), np.asarray(values, acc_dtype)
+    with tracing.device("groupby_mxu", codes, values) as sp:
+        with kernel_x64(acc_dtype):
+            s, c = _gb.groupby_sum(codes, values, num_groups=num_groups,
+                                   interpret=_interp(), acc_dtype=acc_dtype)
+        s, c = sp.fetch(s), sp.fetch(c)
+    return np.stack([s.astype(np.float64), c.astype(np.float64)], axis=1)
 
 
 def segmented_merge(codes, values, num_groups: int,
                     acc_dtype: str = "float32"):
     """(num_groups, 4) per-group [sum, count, min, max] — the reduce-side
     merge of one aggregate state column (DESIGN.md §11)."""
-    with kernel_x64(acc_dtype):
-        return np.asarray(_sm.segmented_merge(
-            np.asarray(codes, np.int32), np.asarray(values, acc_dtype),
-            num_groups=num_groups, interpret=_interp(), acc_dtype=acc_dtype))
+    codes, values = np.asarray(codes, np.int32), np.asarray(values, acc_dtype)
+    with tracing.device("segmented_merge", codes, values) as sp, \
+            kernel_x64(acc_dtype):
+        return sp.fetch(_sm.segmented_merge(
+            codes, values, num_groups=num_groups, interpret=_interp(),
+            acc_dtype=acc_dtype))
 
 
 # -- double-buffered kernel dispatch (DESIGN.md §14) --------------------
@@ -136,10 +145,10 @@ def segmented_merge(codes, values, num_groups: int,
 # double_buffer_map exploits that to overlap chunk i+1's dispatch (which
 # includes host-side decode/staging of its inputs) with chunk i's compute:
 # exactly one launch is kept in flight while the previous result drains.
-# DOUBLE_BUFFER.dispatches counts launches so tests can assert the
-# chunked path actually ran.
+# Each map is one `shark.device` span whose `chunks` attribute counts its
+# launches, so tests can assert the chunked path actually ran.
 
-DOUBLE_BUFFER = {"chunk_rows": 131072, "dispatches": 0}
+DOUBLE_BUFFER = {"chunk_rows": 131072}
 
 
 def double_buffer_map(fn, chunks):
@@ -150,14 +159,15 @@ def double_buffer_map(fn, chunks):
     plain call — same arithmetic, same rounding class."""
     out = []
     inflight = None
-    for chunk in chunks:
-        nxt = fn(chunk)              # async dispatch: returns immediately
-        DOUBLE_BUFFER["dispatches"] += 1
+    with tracing.device("double_buffer") as sp:
+        for chunk in chunks:
+            nxt = fn(chunk)              # async dispatch: returns immediately
+            if inflight is not None:
+                out.append(jax.tree_util.tree_map(sp.fetch, inflight))
+            inflight = nxt
         if inflight is not None:
-            out.append(jax.tree_util.tree_map(np.asarray, inflight))
-        inflight = nxt
-    if inflight is not None:
-        out.append(jax.tree_util.tree_map(np.asarray, inflight))
+            out.append(jax.tree_util.tree_map(sp.fetch, inflight))
+        sp.set(chunks=len(out))
     return out
 
 
@@ -167,23 +177,21 @@ def topk_similarity(x, q, k: int, acc_dtype: str = None):
     index, matching `np.argsort(-scores, kind="stable")[:k]` exactly
     (DESIGN.md §15.3).  Returns numpy arrays of length min(k, rows)."""
     acc = acc_dtype or default_acc_dtype()
-    with kernel_x64(acc):
-        s, i = _tk.topk_similarity(np.asarray(x, acc), np.asarray(q, acc),
-                                   int(k), interpret=_interp(),
+    x, q = np.asarray(x, acc), np.asarray(q, acc)
+    with tracing.device("topk_similarity", x, q) as sp, kernel_x64(acc):
+        s, i = _tk.topk_similarity(x, q, int(k), interpret=_interp(),
                                    acc_dtype=acc)
-        return np.asarray(s), np.asarray(i)
+        return sp.fetch(s), sp.fetch(i)
 
 
 def train_grad(x, y, w, kind: str = "logistic", acc_dtype: str = None):
     """Unnormalized batch gradient `x.T @ (pred(x @ w) - y)` as a numpy
     (d,) vector — the Pallas route of `pde.decide_train_backend`."""
     acc = acc_dtype or default_acc_dtype()
-    with kernel_x64(acc):
-        return np.asarray(_tg.train_grad(np.asarray(x, acc),
-                                         np.asarray(y, acc),
-                                         np.asarray(w, acc), kind,
-                                         interpret=_interp(),
-                                         acc_dtype=acc))
+    x, y, w = np.asarray(x, acc), np.asarray(y, acc), np.asarray(w, acc)
+    with tracing.device("train_grad", x, y, w) as sp, kernel_x64(acc):
+        return sp.fetch(_tg.train_grad(x, y, w, kind, interpret=_interp(),
+                                       acc_dtype=acc))
 
 
 def radix_partition(keys_u32, num_buckets: int, with_counts: bool = True):
@@ -191,8 +199,8 @@ def radix_partition(keys_u32, num_buckets: int, with_counts: bool = True):
     map side of the memory-based shuffle as one fused pass.
     `with_counts=False` skips the histogram matmul (ids-only callers).
     All lanes are 32-bit, so the call traces with x64 off everywhere."""
-    with kernel_x64("float32"):
-        return _rp.radix_partition(np.asarray(keys_u32, np.uint32),
-                                   num_buckets=num_buckets,
+    keys = np.asarray(keys_u32, np.uint32)
+    with tracing.device("radix_partition", keys), kernel_x64("float32"):
+        return _rp.radix_partition(keys, num_buckets=num_buckets,
                                    interpret=_interp(),
                                    with_counts=with_counts)

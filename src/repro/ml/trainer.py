@@ -33,6 +33,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..core import tracing
 from ..core.batch import PartitionBatch
 from ..core.expr import ColumnVal, _x64
 from ..core.pde import PDEConfig, decide_train_backend
@@ -61,24 +62,35 @@ def partition_grad(batch: PartitionBatch, w: np.ndarray, kind: str,
     features; they differ only in where the decode and the matmul run."""
     n = batch.num_rows
     d = decide_train_backend(n, len(w), "train_grad", on_tpu, cfg)
+    with tracing.span("train.partition", route=d.route, rows=n):
+        return d.route, _partition_grad(batch, w, kind, d.route, dtype,
+                                        feature_cols, label_col)
+
+
+def _partition_grad(batch: PartitionBatch, w: np.ndarray, kind: str,
+                    route: str, dtype, feature_cols, label_col) -> np.ndarray:
     sigs, col_args, lsig, largs = partition_recipes(batch, feature_cols,
                                                     label_col)
-    if d.route == "numpy":
+    if route == "numpy":
         x, y = partition_xy_host(batch, feature_cols, label_col, dtype)
         z = x @ w.astype(dtype)
         p = _np_sigmoid(z) if kind == "logistic" else z
-        return "numpy", (x.T @ (p - y.astype(dtype))).astype(dtype)
-    if d.route == "train_grad":
+        return (x.T @ (p - y.astype(dtype))).astype(dtype)
+    if route == "train_grad":
         from ..kernels import ops
-        with _x64():
+        # the assembled features come back to the host and go out again
+        # to the kernel: both legs are counted on the device spans
+        with tracing.device("train_step.assemble", w, col_args,
+                            largs) as sp, _x64():
             x, y = fused_train_step("assemble", sigs, lsig, dtype)(
                 w, col_args, largs)
-            x, y = np.asarray(x), np.asarray(y)
+            x, y = sp.fetch(x), sp.fetch(y)
         g = ops.train_grad(x, y, w, kind)
-        return "train_grad", g.astype(dtype)
-    with _x64():
-        g = fused_train_step(kind, sigs, lsig, dtype)(w, col_args, largs)
-        return "jit", np.asarray(g)
+        return g.astype(dtype)
+    with tracing.device("train_step." + kind, w, col_args, largs) as sp, \
+            _x64():
+        return sp.fetch(fused_train_step(kind, sigs, lsig, dtype)(
+            w, col_args, largs))
 
 
 def partition_kmeans_stats(batch: PartitionBatch, centroids: np.ndarray,
@@ -89,7 +101,14 @@ def partition_kmeans_stats(batch: PartitionBatch, centroids: np.ndarray,
     MXU-shaped inside the fused step), so kernel_eligible is None."""
     n = batch.num_rows
     d = decide_train_backend(n, centroids.shape[1], None, on_tpu, cfg)
-    if d.route == "numpy":
+    with tracing.span("train.partition", route=d.route, rows=n):
+        return _partition_kmeans_stats(batch, centroids, d.route, dtype,
+                                       feature_cols)
+
+
+def _partition_kmeans_stats(batch: PartitionBatch, centroids: np.ndarray,
+                            route: str, dtype, feature_cols):
+    if route == "numpy":
         x, _ = partition_xy_host(batch, feature_cols, None, dtype)
         c = centroids.astype(dtype)
         d2 = ((x * x).sum(1, keepdims=True) - 2.0 * (x @ c.T)
@@ -102,11 +121,12 @@ def partition_kmeans_stats(batch: PartitionBatch, centroids: np.ndarray,
         return "numpy", sums, counts, obj
     sigs, col_args, lsig, largs = partition_recipes(batch, feature_cols,
                                                     None)
-    with _x64():
+    with tracing.device("train_step.kmeans", centroids, col_args) as sp, \
+            _x64():
         sums, counts, obj = fused_train_step("kmeans", sigs, None, dtype)(
             centroids, col_args, ())
-        return ("jit", np.asarray(sums), np.asarray(counts),
-                float(np.asarray(obj)))
+        return ("jit", sp.fetch(sums), sp.fetch(counts),
+                float(sp.fetch(obj)))
 
 
 class IterativeTrainer:
@@ -167,17 +187,19 @@ class IterativeTrainer:
         # RDD — the payload rdd is dep's parent, not its consumer
         fetch_root = ShuffledRDD(dep)
         t0 = time.perf_counter()
-        self.sched.run_map_stage(dep)
-        pieces: List[PartitionBatch] = []
-        for _ in range(self.sched.max_stage_retries):
-            try:
-                pieces = self.bm.fetch_shuffle(
-                    dep.shuffle_id, payload_rdd.num_partitions, [0])
-                break
-            except FetchFailed as ff:     # worker died after the map stage
-                self.sched._recover_lineage(fetch_root, ff)
-        else:
-            raise RuntimeError("exceeded max stage retries (train fetch)")
+        with tracing.span("train.iteration", iteration=self.iteration) as sp:
+            self.sched.run_map_stage(dep)
+            pieces: List[PartitionBatch] = []
+            for _ in range(self.sched.max_stage_retries):
+                try:
+                    pieces = self.bm.fetch_shuffle(
+                        dep.shuffle_id, payload_rdd.num_partitions, [0])
+                    break
+                except FetchFailed as ff:  # worker died after the map stage
+                    self.sched._recover_lineage(fetch_root, ff)
+            else:
+                raise RuntimeError("exceeded max stage retries (train fetch)")
+            sp.set(rows=record.rows_in)
         elapsed = time.perf_counter() - t0
         # per-iteration shuffle output is consumed exactly once: drop it so
         # a 100-iteration fit doesn't pin 100 generations of (tiny) blocks

@@ -26,6 +26,8 @@ import time
 from collections import deque
 from typing import Callable, Dict, Optional, Tuple
 
+from ..core import tracing
+
 
 class AdmissionError(RuntimeError):
     """Queue full: the server refused the query (backpressure)."""
@@ -190,7 +192,9 @@ class FairScheduler:
             handle.started = time.monotonic()
             handle.status = QueryHandle.RUNNING
             try:
-                result, cached = self._run_fn(handle)
+                with tracing.query(client=handle.client,
+                                   queued_s=handle.wait_s):
+                    result, cached = self._run_fn(handle)
                 handle._result = result
                 handle.cached = cached
                 handle.status = QueryHandle.DONE

@@ -35,6 +35,7 @@ from typing import Dict, Optional, Union
 
 import numpy as np
 
+from ..core import tracing
 from ..core.catalog import Catalog, ExternalSource
 from ..core.columnar import Table, from_arrays
 from ..core.pde import PDEConfig
@@ -181,13 +182,17 @@ class SharkServer:
                         scan_cache=self.scan_cache, **self._exec_kw)
 
     def _run_query(self, handle: QueryHandle):
-        if handle.plan is not None:
-            # frame submission: the plan object is owned by the (immutable,
-            # possibly shared) frame — optimize a private copy
-            node = optimize(copy.deepcopy(handle.plan), self.catalog)
-            return self._execute_plan(node)
-
-        stmt = parse(handle.sql)
+        stmt = None
+        with tracing.span("plan"):
+            if handle.plan is not None:
+                # frame submission: the plan object is owned by the
+                # (immutable, possibly shared) frame — optimize a private copy
+                node = optimize(copy.deepcopy(handle.plan), self.catalog)
+            else:
+                stmt = parse(handle.sql)
+                if not isinstance(stmt, CreateStmt):
+                    node = optimize(Binder(self.catalog).bind(stmt),
+                                    self.catalog)
         if isinstance(stmt, CreateStmt):
             from ..core.session import create_table_as
             executor = self.make_executor()
@@ -197,8 +202,6 @@ class SharkServer:
             finally:
                 self._release_shuffles(executor)
             return result, False
-
-        node = optimize(Binder(self.catalog).bind(stmt), self.catalog)
         return self._execute_plan(node)
 
     def _execute_plan(self, node: Node):

@@ -490,19 +490,23 @@ def test_radix_partition_kernel_matches_reference():
 
 @pytest.mark.kernels_interpret
 def test_forced_kernel_session_uses_radix_and_segmented_merge():
-    from repro.core.shuffle import RADIX_KERNEL_CALLS
-    before = RADIX_KERNEL_CALLS["count"]
+    from repro.core import tracing
     sess = _mk(pde_config=PDEConfig(segment_force_kernels=True,
                                     reduce_force_compiled=True))
     ref = _mk()
     q = "SELECT ds, SUM(v) AS s FROM t JOIN d ON t.k = d.dk GROUP BY ds"
-    got, want = sess.sql_np(q), ref.sql_np(q)
+    with tracing.recording():
+        got = sess.sql_np(q)
+    radix_calls = sum(r.name == "shark.device"
+                      and r.attrs["program"] == "radix_partition"
+                      for r in tracing.records())
+    want = ref.sql_np(q)
     og, ow = np.argsort(got["ds"]), np.argsort(want["ds"])
     assert np.asarray(got["ds"])[og].tolist() == \
         np.asarray(want["ds"])[ow].tolist()
     np.testing.assert_allclose(np.asarray(got["s"])[og],
                                np.asarray(want["s"])[ow], rtol=1e-9)
-    assert RADIX_KERNEL_CALLS["count"] > before
+    assert radix_calls > 0
     routes = sess.metrics().segment_routes()
     assert routes.get("segmented_merge", 0) > 0, routes
     sess.shutdown()

@@ -21,7 +21,7 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 import pytest
 
-from repro.core import DType, Schema
+from repro.core import DType, Schema, tracing
 from repro.core.catalog import ExternalSource
 from repro.server import SharkServer
 
@@ -33,6 +33,14 @@ QUERY = ("SELECT sval, COUNT(*) AS c, SUM(rev) AS total FROM fact "
          "JOIN small_d ON fact.sk = small_d.skey "
          "JOIN mid_d ON fact.mk = mid_d.mkey "
          "GROUP BY sval")
+
+
+def _stage_events():
+    """(seq, kind, shuffle_id, split) of each recorded stage event, in the
+    order they happened (record ids are issued in that order)."""
+    return sorted((r.id, r.name[len("shark.stage."):], r.attrs["shuffle_id"],
+                   r.attrs["split"]) for r in tracing.records()
+                  if r.name.startswith("shark.stage."))
 
 
 def _make_server() -> SharkServer:
@@ -472,7 +480,7 @@ def test_worker_loss_mid_fused_stage_with_reduce_started():
             deadline = time.monotonic() + 10.0
             while time.monotonic() < deadline:
                 if any(e[1] == "reduce-fetch" and e[2] == agg_sid
-                       for e in scheduler.stage_events):
+                       for e in _stage_events()):
                     break
                 time.sleep(0.005)
             victim = None
@@ -514,14 +522,15 @@ def test_worker_loss_mid_fused_stage_with_reduce_started():
 
         scheduler.run_map_stage = chaotic_map_stage
         try:
-            res = sess.sql_np(QUERY_FUSED)
+            with tracing.recording():
+                res = sess.sql_np(QUERY_FUSED)
         finally:
             scheduler.run_map_stage = orig_map_stage
         got = (int(res["c"][0]), round(float(res["total"][0]), 6))
         assert state["killed"] is not None, "kill never fired mid-stage"
         assert got == baseline, "mid-fused-stage worker loss diverged"
         _assert_shuffles_released(srv)
-        ev = scheduler.stage_events
+        ev = _stage_events()
         fetches = [e for e in ev
                    if e[1] == "reduce-fetch" and e[2] == state["sid"]]
         dones = [e for e in ev

@@ -4,8 +4,9 @@ Deterministic probes of the fused-stage machinery, complementing the
 seeded differential grid in test_oracle_differential.py:
 
   * the pipelined scheduler observably starts a reduce task BEFORE the map
-    stage drains (event-order probe on `Scheduler.stage_events`, with a
-    straggler injected on the later map splits);
+    stage drains (event-order probe on the recorded `shark.stage.*`
+    events of `repro.core.tracing`, with a straggler injected on the later
+    map splits);
   * the reduce result computed by the pipeline is consumed through
     `PipelinedShuffledRDD` (hit counter) and matches the pull path;
   * double-buffered Pallas dispatch (colscan chunking, radix-partition
@@ -19,11 +20,26 @@ seeded differential grid in test_oracle_differential.py:
 import numpy as np
 import pytest
 
-from repro.core import DType, Schema, SharkSession
+from repro.core import DType, Schema, SharkSession, tracing
 from repro.core.pde import (PDEConfig, decide_pipelined_reduce,
                             decide_stage_fusion)
 
 pytestmark = pytest.mark.tier1
+
+
+def _stage_events():
+    """(seq, kind, shuffle_id, split) of each recorded stage event, in the
+    order they happened (record ids are issued in that order)."""
+    return sorted((r.id, r.name[len("shark.stage."):], r.attrs["shuffle_id"],
+                   r.attrs["split"]) for r in tracing.records()
+                  if r.name.startswith("shark.stage."))
+
+
+def _dispatches() -> int:
+    """Kernel launches made through double_buffer_map while recording."""
+    return sum(r.attrs["chunks"] for r in tracing.records()
+               if r.name == "shark.device"
+               and r.attrs["program"] == "double_buffer")
 
 FORCE_KERNELS = PDEConfig(segment_force_kernels=True,
                           segment_kernel_min_rows=256,
@@ -90,11 +106,11 @@ def test_pull_fallback_when_pool_is_saturated():
     """With map splits saturating the pool the boundary must skip the
     overlap thread (no reduce-fetch event) and still be row-identical."""
     sess, data = _star_session(partitions=4)   # 4 splits, 4 pool threads
-    got = sess.sql_np("SELECT SUM(fv) AS s, COUNT(*) AS c FROM t")
+    with tracing.recording():
+        got = sess.sql_np("SELECT SUM(fv) AS s, COUNT(*) AS c FROM t")
     np.testing.assert_allclose(got["s"], [data["fv"].sum()], rtol=1e-9)
     assert int(got["c"][0]) == len(data["fv"])
-    assert not any(e[1] == "reduce-fetch"
-                   for e in sess.ctx.scheduler.stage_events)
+    assert not any(e[1] == "reduce-fetch" for e in _stage_events())
     assert any("sequential fetch" in r
                for r in sess.metrics().pipeline_decisions)
     # the fused map side is unaffected by the reduce-side admission gate
@@ -120,11 +136,12 @@ def test_reduce_starts_before_map_stage_drains(monkeypatch):
         return orig(dep, *a, **kw)
 
     monkeypatch.setattr(sched, "run_map_stage", straggle_then_run)
-    got = sess.sql_np("SELECT SUM(fv) AS s, COUNT(*) AS c FROM t")
+    with tracing.recording():
+        got = sess.sql_np("SELECT SUM(fv) AS s, COUNT(*) AS c FROM t")
     np.testing.assert_allclose(got["s"], [data["fv"].sum()], rtol=1e-9)
     assert int(got["c"][0]) == len(data["fv"])
 
-    ev = sched.stage_events
+    ev = _stage_events()
     fetches = [e for e in ev if e[1] == "reduce-fetch"]
     assert fetches, f"no pipelined reduce-fetch event: {ev}"
     shuffle_id = fetches[0][2]
@@ -171,11 +188,11 @@ def test_pipelined_reduce_failure_falls_back_to_pull(monkeypatch):
 
     monkeypatch.setattr(Scheduler, "_pipelined_reduce", crash)
     sess, data = _star_session()
-    got = sess.sql_np("SELECT SUM(fv) AS s, COUNT(*) AS c FROM t")
+    with tracing.recording():
+        got = sess.sql_np("SELECT SUM(fv) AS s, COUNT(*) AS c FROM t")
     np.testing.assert_allclose(got["s"], [data["fv"].sum()], rtol=1e-9)
     assert int(got["c"][0]) == len(data["fv"])
-    assert not any(e[1] == "reduce-done"
-                   for e in sess.ctx.scheduler.stage_events)
+    assert not any(e[1] == "reduce-done" for e in _stage_events())
     sess.shutdown()
 
 
@@ -208,12 +225,13 @@ def test_double_buffered_colscan_matches_single_shot(monkeypatch):
     sess_n.shutdown()
 
     monkeypatch.setitem(kernel_ops.DOUBLE_BUFFER, "chunk_rows", 512)
-    monkeypatch.setitem(kernel_ops.DOUBLE_BUFFER, "dispatches", 0)
     sess_k, _ = _star_session(pde_config=FORCE_KERNELS, rows=5000)
-    got = sess_k.sql_np("SELECT COUNT(*) AS c, SUM(fv) AS s, MIN(fv) AS mn,"
-                        " MAX(fv) AS mx FROM t WHERE fn BETWEEN 20 AND 80")
+    with tracing.recording():
+        got = sess_k.sql_np("SELECT COUNT(*) AS c, SUM(fv) AS s, MIN(fv) AS "
+                            "mn, MAX(fv) AS mx FROM t WHERE fn BETWEEN 20 "
+                            "AND 80")
     assert sess_k.metrics().segment_routes().get("colscan", 0) > 0
-    assert kernel_ops.DOUBLE_BUFFER["dispatches"] > 1, \
+    assert _dispatches() > 1, \
         "colscan never took the double-buffered chunk path"
     for k in want:
         np.testing.assert_allclose(got[k], want[k], rtol=1e-12)
@@ -228,9 +246,9 @@ def test_double_buffered_radix_partition_is_bit_identical(monkeypatch):
     k = rng.integers(0, 1 << 40, 5000).astype(np.uint64)
     full = _kernel_buckets(k, 8)
     monkeypatch.setitem(kernel_ops.DOUBLE_BUFFER, "chunk_rows", 512)
-    monkeypatch.setitem(kernel_ops.DOUBLE_BUFFER, "dispatches", 0)
-    chunked = _kernel_buckets(k, 8)
-    assert kernel_ops.DOUBLE_BUFFER["dispatches"] == int(np.ceil(5000 / 512))
+    with tracing.recording():
+        chunked = _kernel_buckets(k, 8)
+    assert _dispatches() == int(np.ceil(5000 / 512))
     np.testing.assert_array_equal(full, chunked)
 
 
