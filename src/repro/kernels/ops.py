@@ -1,8 +1,9 @@
 """Public wrappers for the Pallas kernels.
 
 Each wrapper prepares its inputs on the host (int32 lanes for codes and
-integer filters, the accumulation dtype for values) and calls its kernel.
-On a TPU the kernels compile to Mosaic and accumulate in float32; on any
+integer filters, the accumulation dtype for values) and calls its kernel;
+`train_grad_inputs` and `train_grad_padded` take device arrays as they
+are, so inputs kept on the device stay there.  On a TPU the kernels compile to Mosaic and accumulate in float32; on any
 other platform they run in interpret mode and accumulate in float64, so
 the engine matches the numpy oracle to rounding (semantics validated
 against ref.py).
@@ -186,12 +187,34 @@ def topk_similarity(x, q, k: int, acc_dtype: str = None):
 
 def train_grad(x, y, w, kind: str = "logistic", acc_dtype: str = None):
     """Unnormalized batch gradient `x.T @ (pred(x @ w) - y)` as a numpy
-    (d,) vector — the Pallas route of `pde.decide_train_backend`."""
+    (d,) vector, from host arrays.  The trainer's `train_grad` route lays
+    its inputs out once (`train_grad_inputs`) and calls
+    `train_grad_padded` every iteration."""
     acc = acc_dtype or default_acc_dtype()
     x, y, w = np.asarray(x, acc), np.asarray(y, acc), np.asarray(w, acc)
     with tracing.device("train_grad", x, y, w) as sp, kernel_x64(acc):
         return sp.fetch(_tg.train_grad(x, y, w, kind, interpret=_interp(),
                                        acc_dtype=acc))
+
+
+def train_grad_inputs(x, y, acc_dtype: str = None):
+    """Device arrays (xp, yp): `x`, `y` laid out as the `train_grad` kernel
+    reads them.  Takes device arrays as they are, so features assembled on
+    the device stay there; the caller's span counts what it hands in."""
+    acc = acc_dtype or default_acc_dtype()
+    with kernel_x64(acc):
+        return _tg.pad_inputs(x, y, acc_dtype=acc)
+
+
+def train_grad_padded(xp, yp, w, kind: str = "logistic",
+                      acc_dtype: str = None):
+    """`train_grad` over inputs `train_grad_inputs` laid out on the device:
+    only `w` goes up, and the numpy (d,) gradient comes back."""
+    acc = acc_dtype or default_acc_dtype()
+    w = np.asarray(w, acc)
+    with tracing.device("train_grad", w) as sp, kernel_x64(acc):
+        return sp.fetch(_tg.train_grad_padded(
+            xp, yp, w, kind, interpret=_interp(), acc_dtype=acc))
 
 
 def radix_partition(keys_u32, num_buckets: int, with_counts: bool = True):

@@ -232,9 +232,12 @@ def fused_train_step(kind: str, sigs: tuple, label_sig, dtype) -> Callable:
 
     kinds: "logistic" / "linear" -> summed gradient (d,);
            "kmeans"              -> (per-centroid sums, counts, objective);
-           "assemble"            -> (x, y) for routes that need the dense
-                                    matrix host-side (the Pallas train_grad
-                                    kernel) without paying decode_np.
+           "assemble"            -> (x, y) as device arrays, for the
+                                    Pallas train_grad route, which lays
+                                    them out for its kernel and keeps them
+                                    on the device for the fit
+                                    (ml/trainer.py), without paying
+                                    decode_np.
     """
     key = (kind, sigs, label_sig, str(np.dtype(dtype)))
     fn = _FUSED_CACHE.get(key)

@@ -34,11 +34,12 @@ class LinearRegression:
         features_rdd = as_features_rdd(data, feature_cols, label_col,
                                        map_rows, dtype)
         features_rdd.cache()
-        trainer = IterativeTrainer(features_rdd, "linreg", dtype=dtype)
-        self.metrics = trainer.metrics
-        for _ in range(self.iterations):
-            g, n = trainer.gradient_iteration(self.w, "linear")
-            self.w = self.w - self.lr * (g / max(n, 1)).astype(self.w.dtype)
+        with IterativeTrainer(features_rdd, "linreg", dtype=dtype) as trainer:
+            self.metrics = trainer.metrics
+            for _ in range(self.iterations):
+                g, n = trainer.gradient_iteration(self.w, "linear")
+                self.w = self.w - self.lr * (g / max(n, 1)).astype(
+                    self.w.dtype)
         return self
 
     def predict(self, x: np.ndarray) -> np.ndarray:

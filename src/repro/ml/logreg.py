@@ -6,9 +6,14 @@ every partition routes through `decide_train_backend` — numpy oracle,
 fused jitted assemble+train (decode of encoded blocks traced into the XLA
 program), or the Pallas `train_grad` kernel — and the master reduces the
 per-partition gradients, exactly the paper's `data.map(gradient).reduce(+)`
-loop.  Per-iteration cost on cached encoded partitions is one pass of
-MXU-bound compute plus an O(dims) aggregation; a lost worker only
-recomputes its partitions (lineage), even mid-iteration.
+loop.  Per-iteration cost on cached encoded partitions is one pass over
+the features plus an O(dims) aggregation.  On the `train_grad` route the
+features are laid out on the device by the first iteration and stay in
+HBM until `fit` returns or raises, so later iterations move only the
+weights and the gradient between host and device (ml/trainer.py); the
+other routes ship the cached encoded blocks each iteration.  A lost
+worker only recomputes its partitions (lineage), even mid-iteration,
+and their device copies are laid out anew.
 
 After `fit()`, `self.metrics` (an ExecMetrics) carries one SegmentRecord
 per iteration with the routes taken, plus `train_iterations` timings.
@@ -53,11 +58,12 @@ class LogisticRegression:
         features_rdd = as_features_rdd(data, feature_cols, label_col,
                                        map_rows, dtype)
         features_rdd.cache()
-        trainer = IterativeTrainer(features_rdd, "logreg", dtype=dtype)
-        self.metrics = trainer.metrics
-        for _ in range(self.iterations):
-            g, n = trainer.gradient_iteration(self.w, "logistic")
-            self.w = self.w - self.lr * (g / max(n, 1)).astype(self.w.dtype)
+        with IterativeTrainer(features_rdd, "logreg", dtype=dtype) as trainer:
+            self.metrics = trainer.metrics
+            for _ in range(self.iterations):
+                g, n = trainer.gradient_iteration(self.w, "logistic")
+                self.w = self.w - self.lr * (g / max(n, 1)).astype(
+                    self.w.dtype)
         return self
 
     def loss(self, data, feature_cols=None, label_col=None) -> float:

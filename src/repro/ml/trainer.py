@@ -19,6 +19,16 @@ same `run_map_stage` machinery SQL shuffles use — not a private loop:
     `train_grad` gradient kernel on large partitions when kernels are
     forced/on-TPU.
 
+Where the features live during a fit: on the numpy and jit routes, in
+the cached FeatureRDD's host blocks, shipped encoded every iteration.  On
+the `train_grad` route, the first iteration to reach a partition
+assembles its features on the device, lays them out for the kernel and
+keeps them there (`ResidentFeatures`); every later iteration uploads
+only the (d,) weights and reads the (d,) gradient back, so the device
+reads the features from HBM and nothing else crosses.  The entries are
+held under a share of the device's memory and deleted when the fit
+returns or raises (the estimators use the trainer as a context manager).
+
 Observability mirrors the SQL executor: one `SegmentRecord` per iteration
 (table `<train:name>`, consumer "train") tallies partitions/rows/routes,
 and `ExecMetrics.train_iterations` records per-iteration wall-clock —
@@ -29,8 +39,10 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
 from typing import Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from ..core import tracing
@@ -54,39 +66,60 @@ def _np_sigmoid(z: np.ndarray) -> np.ndarray:
     return out
 
 
-def partition_grad(batch: PartitionBatch, w: np.ndarray, kind: str,
-                   cfg: PDEConfig, dtype, feature_cols, label_col,
-                   on_tpu: bool):
+def partition_grad(split: int, batch: PartitionBatch, w: np.ndarray,
+                   kind: str, cfg: PDEConfig, dtype, feature_cols, label_col,
+                   on_tpu: bool, store: "ResidentFeatures"):
     """(route, unnormalized gradient) for one feature partition, routed by
     the PDE.  All three routes compute the same sum-of-residual-weighted
-    features; they differ only in where the decode and the matmul run."""
+    features; they differ only in where the decode and the matmul run.
+    The span's `resident` says whether the `train_grad` route found the
+    partition's inputs on the device ("hit"), laid them out and kept them
+    ("fill"), or neither ("none")."""
     n = batch.num_rows
     d = decide_train_backend(n, len(w), "train_grad", on_tpu, cfg)
-    with tracing.span("train.partition", route=d.route, rows=n):
-        return d.route, _partition_grad(batch, w, kind, d.route, dtype,
-                                        feature_cols, label_col)
+    with tracing.span("train.partition", route=d.route, rows=n) as sp:
+        if d.route == "train_grad":
+            resident, g = _kernel_grad(split, batch, w, kind, dtype,
+                                       feature_cols, label_col, store)
+        else:
+            resident, g = "none", _partition_grad(
+                batch, w, kind, d.route, dtype, feature_cols, label_col)
+        sp.set(resident=resident)
+        return d.route, g
+
+
+def _kernel_grad(split: int, batch: PartitionBatch, w: np.ndarray, kind: str,
+                 dtype, feature_cols, label_col, store: "ResidentFeatures"):
+    """(resident, gradient) on the Pallas `train_grad` route: the kernel
+    reads the partition's inputs from `store`, or from a fill that
+    assembles and lays them out on the device, where they stay."""
+    from ..kernels import ops
+    xy = store.get(split, batch)
+    resident = "hit"
+    if xy is None:
+        sigs, col_args, lsig, largs = partition_recipes(batch, feature_cols,
+                                                        label_col)
+        with tracing.device("train_step.assemble", w, col_args,
+                            largs) as sp:
+            with _x64():
+                x, y = fused_train_step("assemble", sigs, lsig, dtype)(
+                    w, col_args, largs)
+            xy = ops.train_grad_inputs(x, y)
+            pinned = store.keep(split, batch, *xy)
+            sp.set(resident_bytes=pinned)
+        resident = "fill" if pinned else "none"
+    return resident, ops.train_grad_padded(*xy, w, kind).astype(dtype)
 
 
 def _partition_grad(batch: PartitionBatch, w: np.ndarray, kind: str,
                     route: str, dtype, feature_cols, label_col) -> np.ndarray:
-    sigs, col_args, lsig, largs = partition_recipes(batch, feature_cols,
-                                                    label_col)
     if route == "numpy":
         x, y = partition_xy_host(batch, feature_cols, label_col, dtype)
         z = x @ w.astype(dtype)
         p = _np_sigmoid(z) if kind == "logistic" else z
         return (x.T @ (p - y.astype(dtype))).astype(dtype)
-    if route == "train_grad":
-        from ..kernels import ops
-        # the assembled features come back to the host and go out again
-        # to the kernel: both legs are counted on the device spans
-        with tracing.device("train_step.assemble", w, col_args,
-                            largs) as sp, _x64():
-            x, y = fused_train_step("assemble", sigs, lsig, dtype)(
-                w, col_args, largs)
-            x, y = sp.fetch(x), sp.fetch(y)
-        g = ops.train_grad(x, y, w, kind)
-        return g.astype(dtype)
+    sigs, col_args, lsig, largs = partition_recipes(batch, feature_cols,
+                                                    label_col)
     with tracing.device("train_step." + kind, w, col_args, largs) as sp, \
             _x64():
         return sp.fetch(fused_train_step(kind, sigs, lsig, dtype)(
@@ -101,7 +134,8 @@ def partition_kmeans_stats(batch: PartitionBatch, centroids: np.ndarray,
     MXU-shaped inside the fused step), so kernel_eligible is None."""
     n = batch.num_rows
     d = decide_train_backend(n, centroids.shape[1], None, on_tpu, cfg)
-    with tracing.span("train.partition", route=d.route, rows=n):
+    with tracing.span("train.partition", route=d.route, rows=n,
+                      resident="none"):
         return _partition_kmeans_stats(batch, centroids, d.route, dtype,
                                        feature_cols)
 
@@ -129,9 +163,77 @@ def _partition_kmeans_stats(batch: PartitionBatch, centroids: np.ndarray,
                 float(sp.fetch(obj)))
 
 
+# Share of the device's memory that one fit's kernel-ready features may pin
+# (ResidentFeatures).
+RESIDENT_SHARE = 0.5
+
+
+def resident_budget() -> Optional[int]:
+    """Bytes the resident features may pin: RESIDENT_SHARE of the device's
+    `bytes_limit`, or None (no cap) where the device reports no limit."""
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    return int(limit * RESIDENT_SHARE) if limit else None
+
+
+class ResidentFeatures:
+    """The `train_grad` route's kernel-ready inputs (xp, yp) of each feature
+    partition, kept on the device for one fit (DESIGN.md §15.2).
+
+    The first iteration to reach a partition assembles its features on the
+    device, lays them out for the kernel and keeps them there (a fill);
+    every later iteration uploads only `w` (a hit).  An entry is keyed by
+    its split and by the cached batch it was built from, so a batch
+    recomputed from lineage misses and refills.  The entries stay under
+    `resident_budget()`; a partition beyond it lays out its inputs anew
+    each iteration and keeps nothing.  `release` deletes every entry and
+    keeps none after it."""
+
+    def __init__(self):
+        self.budget = resident_budget()
+        self._lock = threading.Lock()
+        # split -> (weak reference to the batch, xp, yp, device bytes)
+        self.entries: Dict[int, tuple] = {}
+        self.pinned = 0
+        self.closed = False
+
+    def get(self, split: int, batch: PartitionBatch):
+        """(xp, yp) built from this very batch, or None."""
+        with self._lock:
+            entry = self.entries.get(split)
+        if entry is None or entry[0]() is not batch:
+            return None
+        return entry[1], entry[2]
+
+    def keep(self, split: int, batch: PartitionBatch, xp, yp) -> int:
+        """Keep (xp, yp) as `split`'s entry if the budget holds them: the
+        bytes pinned, or 0."""
+        nbytes = xp.on_device_size_in_bytes() + yp.on_device_size_in_bytes()
+        with self._lock:
+            stale = self.entries.pop(split, None)
+            if stale is not None:
+                self.pinned -= stale[3]
+            if self.closed or (self.budget is not None
+                               and self.pinned + nbytes > self.budget):
+                return 0
+            self.entries[split] = (weakref.ref(batch), xp, yp, nbytes)
+            self.pinned += nbytes
+        return nbytes
+
+    def release(self) -> None:
+        with self._lock:
+            self.closed = True
+            entries, self.entries = self.entries, {}
+            self.pinned = 0
+        for _, xp, yp, _ in entries.values():
+            xp.delete()
+            yp.delete()
+
+
 class IterativeTrainer:
     """Drives an estimator's iterations as scheduled map stages over a
-    cached features RDD (module docstring)."""
+    cached features RDD (module docstring).  Used as a context manager,
+    it releases the partitions' resident features when the fit leaves
+    the block, by return or by raise."""
 
     def __init__(self, features_rdd: RDD, name: str,
                  cfg: Optional[PDEConfig] = None,
@@ -144,6 +246,7 @@ class IterativeTrainer:
         self.sched = features_rdd.ctx.scheduler
         self.bm = features_rdd.ctx.block_manager
         self.iteration = 0
+        self.resident = ResidentFeatures()
         if isinstance(features_rdd, FeatureRDD):
             self.feature_cols = features_rdd.feature_cols
             self.label_col = features_rdd.label_col
@@ -156,6 +259,13 @@ class IterativeTrainer:
             self.feature_cols = None
             self.label_col = None
             self.dtype = np.dtype(dtype)
+
+    def __enter__(self) -> "IterativeTrainer":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.resident.release()
+        return False
 
     def run_stage(self, make_payload: Callable[[int, PartitionBatch],
                                                Dict[str, ColumnVal]]
@@ -216,9 +326,9 @@ class IterativeTrainer:
         tpu = on_tpu()
 
         def payload(split, batch):
-            route, g = partition_grad(batch, w, kind, self.cfg, self.dtype,
-                                      self.feature_cols, self.label_col,
-                                      tpu)
+            route, g = partition_grad(split, batch, w, kind, self.cfg,
+                                      self.dtype, self.feature_cols,
+                                      self.label_col, tpu, self.resident)
             return route, {"grad": ColumnVal(g[None, :]),
                            "count": ColumnVal(
                                np.array([batch.num_rows], np.int64))}

@@ -75,6 +75,11 @@ CASES = {
     "train_grad-1Mx8": (_tg.train_grad,
                         [((M, 8), F32), ((M,), F32), ((8,), F32)],
                         ("logistic",), {"acc_dtype": "float32"}),
+    # the resident route: inputs laid out once, the kernel alone per call
+    "train_grad_padded-374784x10": (_tg.train_grad_padded,
+                                    [((374784, 128), F32),
+                                     ((374784, 128), F32), ((10,), F32)],
+                                    ("logistic",), {"acc_dtype": "float32"}),
     "topk_similarity-65536x64": (_tk.topk_similarity,
                                  [((65536, 64), F32), ((64,), F32)], (10,),
                                  {"acc_dtype": "float32"}),
