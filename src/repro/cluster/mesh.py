@@ -8,6 +8,13 @@ Placement is physical-layer state only — it never appears in a logical
 plan, so explain() output and plan fingerprints are byte-identical with
 sharding on or off.
 
+The context also keeps the lanes of placed partitions on the devices
+between dispatches (`ResidentLanes`): a mesh program reads a column it has
+read before from device memory, and ships only what changed per query.
+An entry belongs to one placement generation and to the host arrays it
+was built from, so a device loss or a partition rebuilt from lineage
+misses and refills from the host.
+
 Device loss is modeled the way worker loss is in the runtime scheduler:
 ``kill_device(slot)`` marks the slot dead and bumps the placement
 *generation*.  A dispatch that observes a generation change (or catches
@@ -20,8 +27,13 @@ single-host path already has).
 from __future__ import annotations
 
 import threading
+import weakref
+from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+# share of the mesh devices' memory the resident lanes may hold
+RESIDENT_SHARE = 0.25
 
 
 class DeviceLost(RuntimeError):
@@ -45,6 +57,89 @@ class MeshPlacement:
     @property
     def n_devices(self) -> int:
         return len(self.alive_slots)
+
+
+class ResidentLanes:
+    """Device copies of placed partitions' lanes, kept between dispatches.
+
+    An entry is keyed by a tag (which lane of which column) and by the
+    identity of the host arrays it was made from, held by weak reference:
+    it hits only while those very arrays live and the placement generation
+    it was built at holds.  Entries stay under `budget` device bytes,
+    least recently used first out; one larger than the budget is not kept.
+    """
+
+    def __init__(self, budget: Optional[int]):
+        self.budget = budget
+        self._lock = threading.RLock()
+        # key -> (generation, weak refs to the sources, value, device bytes)
+        self._entries: "OrderedDict[tuple, tuple]" = OrderedDict()
+        self.pinned = 0
+
+    @staticmethod
+    def _key(tag: str, sources: Sequence[Any]) -> tuple:
+        return (tag,) + tuple(id(s) for s in sources)
+
+    def get(self, tag: str, sources: Sequence[Any], generation: int):
+        """The value kept for `sources` at `generation`, or None."""
+        key = self._key(tag, sources)
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None:
+                return None
+            if entry[0] != generation or any(
+                    r() is not s for r, s in zip(entry[1], sources)):
+                self._drop(key)
+                return None
+            self._entries.move_to_end(key)
+            return entry[2]
+
+    def keep(self, tag: str, sources: Sequence[Any], generation: int,
+             value, nbytes: int) -> int:
+        """Keep `value` (holding `nbytes` device bytes) for `sources`: the
+        bytes pinned, or 0 where the budget cannot hold it.  The entry goes
+        when any of its sources does."""
+        key = self._key(tag, sources)
+        gone = lambda _r, k=key: self._discard(k)  # noqa: E731
+        refs = tuple(weakref.ref(s, gone) for s in sources)
+        with self._lock:
+            self._discard(key)
+            if self.budget is not None:
+                if nbytes > self.budget:
+                    return 0
+                while self.pinned + nbytes > self.budget:
+                    self._drop(next(iter(self._entries)))
+            self._entries[key] = (generation, refs, value, nbytes)
+            self.pinned += nbytes
+        return nbytes
+
+    def _drop(self, key: tuple) -> None:
+        self.pinned -= self._entries.pop(key)[3]
+
+    def _discard(self, key: tuple) -> None:
+        with self._lock:
+            if key in self._entries:
+                self._drop(key)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._entries.clear()
+            self.pinned = 0
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+
+def resident_budget(devices) -> Optional[int]:
+    """RESIDENT_SHARE of the devices' summed `bytes_limit`, or None (no
+    cap) where a device reports no limit."""
+    total = 0
+    for d in devices:
+        limit = (d.memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            return None
+        total += limit
+    return int(total * RESIDENT_SHARE)
 
 
 class MeshContext:
@@ -76,6 +171,7 @@ class MeshContext:
         self.dispatches = 0
         self.retries = 0                # dispatches re-run after device loss
         self._mesh_cache: Dict[int, object] = {}    # generation -> Mesh
+        self.resident = ResidentLanes(resident_budget(devs))
 
     # -- device liveness ------------------------------------------------------
 
@@ -98,12 +194,15 @@ class MeshContext:
                 raise RuntimeError("cannot kill the last mesh device")
             self.alive[slot] = False
             self.generation += 1
+        self.resident.clear()
 
     def revive_all(self) -> None:
         with self.lock:
-            if not all(self.alive):
-                self.alive = [True] * len(self.devices)
-                self.generation += 1
+            if all(self.alive):
+                return
+            self.alive = [True] * len(self.devices)
+            self.generation += 1
+        self.resident.clear()
 
     # -- placement ------------------------------------------------------------
 

@@ -207,6 +207,44 @@ def partial_aggregate(batch: PartitionBatch, group_cols: Sequence[str],
     return PartitionBatch(out)
 
 
+def sorted_partial_aggregate(key: ColumnVal, values: Optional[np.ndarray],
+                             key_col: str, aggs: Sequence[AggSpec]
+                             ) -> PartitionBatch:
+    """`partial_aggregate` of rows sorted by their one group key, for
+    COUNT, SUM and AVG of `values`: each key's rows are one run, so a
+    group's states are reductions over its run and no sort or hash of the
+    rows is needed.  Groups come out in key order, as from
+    `partial_aggregate`, with the same dtypes; float sums are float64
+    additions of the same values, in another order."""
+    k = np.asarray(key.arr)
+    n = k.shape[0]
+    starts = np.flatnonzero(np.concatenate([[n > 0], k[1:] != k[:-1]]))
+    cnt = np.diff(np.append(starts, n)).astype(np.int64)
+    out: Dict[str, ColumnVal] = {
+        key_col: ColumnVal(k[starts], key.sdict, key.sorted_dict)}
+
+    def run_sums(dtype) -> np.ndarray:
+        if not n:
+            return np.zeros(0, dtype)
+        return np.add.reduceat(values.astype(dtype), starts)
+
+    for spec in aggs:
+        cols = _state_cols(spec)
+        if spec.func == AggFunc.COUNT:
+            out[cols[0]] = ColumnVal(cnt)
+        elif spec.func == AggFunc.SUM:
+            exact = (np.issubdtype(values.dtype, np.integer)
+                     or values.dtype.kind == "b")
+            out[cols[0]] = ColumnVal(run_sums(np.int64 if exact
+                                              else np.float64))
+        elif spec.func == AggFunc.AVG:
+            out[cols[0]] = ColumnVal(run_sums(np.float64))
+            out[cols[1]] = ColumnVal(cnt)
+        else:
+            raise NotImplementedError(spec.func)
+    return PartitionBatch(out)
+
+
 def merge_aggregate(batch: PartitionBatch, group_cols: Sequence[str],
                     aggs: Sequence[AggSpec]) -> PartitionBatch:
     """Reduce-side final merge of partial states (one row per group) — the

@@ -52,10 +52,20 @@ def merge_string_dicts(dicts: Sequence[np.ndarray]
         d = dicts[0]
         if len(d) <= 1 or bool(np.all(d[:-1] < d[1:])):
             return d, [np.arange(len(d), dtype=np.int32)]
-    unified = np.unique(np.concatenate(dicts)) if dicts \
-        else np.zeros(0, np.str_)
-    remaps = [np.searchsorted(unified, d).astype(np.int32) for d in dicts]
-    return unified, remaps
+    if not dicts:
+        return np.zeros(0, np.str_), []
+    # one stable argsort of the concatenation: sorted inputs are runs it
+    # merges rather than re-sorts; each entry's rank among the distinct
+    # values is its code in the unified dictionary
+    cat = np.concatenate(dicts)
+    order = np.argsort(cat, kind="stable")
+    ordered = cat[order]
+    first = np.ones(len(cat), bool)
+    first[1:] = ordered[1:] != ordered[:-1]
+    codes = np.empty(len(cat), np.int32)
+    codes[order] = np.cumsum(first) - 1
+    bounds = np.cumsum([len(d) for d in dicts])[:-1]
+    return ordered[first], np.split(codes, bounds)
 
 
 @dataclasses.dataclass
@@ -191,7 +201,15 @@ class PartitionBatch:
         out: Dict[str, ColumnVal] = {}
         for n in names:
             vals = [b.cols[n] for b in batches]
-            if all(v.is_string for v in vals):
+            if all(v.is_string and v.sorted_dict and v.sdict is vals[0].sdict
+                   for v in vals):
+                # one sorted dictionary behind every piece (a table-wide
+                # domain, DESIGN.md §13.1): the codes already compare
+                codes = np.empty(total, np.int32)
+                for v, lo, hi in zip(vals, offsets, offsets[1:]):
+                    codes[lo:hi] = np.asarray(v.arr)
+                out[n] = ColumnVal(codes, vals[0].sdict, True)
+            elif all(v.is_string for v in vals):
                 # compact each piece's dictionary to the codes it actually
                 # references first: a shuffle bucket keeps its map
                 # partition's FULL dictionary, so merging uncompacted dicts

@@ -20,6 +20,9 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import os
+import threading
+import weakref
 from typing import Callable, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
@@ -28,6 +31,7 @@ from .compression import Encoded, Encoding, decode_np, encode, recompress
 from .types import DType, Field, Schema
 
 ENUM_DISTINCT_LIMIT = 64  # paper: keep distinct values "if the number is small"
+LOAD_THREADS = 16         # most load tasks of one table built at once
 
 
 @dataclasses.dataclass
@@ -324,6 +328,35 @@ def build_partition(index: int, schema: Schema,
     return Partition(index, cols)
 
 
+class StringDomain:
+    """One STRING column's values across a whole table: the partitions'
+    dictionaries unified once (`batch.merge_string_dicts`) into the sorted,
+    unique `values`, with each partition dictionary's remap into it — so
+    codes from different partitions compare, hash and travel between
+    devices as one int32 lane (DESIGN.md §13.1)."""
+
+    def __init__(self, dicts: Sequence[np.ndarray]):
+        from .batch import merge_string_dicts
+        self.values, remaps = merge_string_dicts(dicts)
+        # keyed by id with a weak reference: a dictionary that was spilled
+        # and faulted back in is a new object and takes the lookup below
+        self._remaps = {id(d): (weakref.ref(d), r)
+                        for d, r in zip(dicts, remaps)}
+
+    def codes(self, codes: np.ndarray, sdict: np.ndarray
+              ) -> Optional[np.ndarray]:
+        """`codes` into the partition dictionary `sdict` as table-wide
+        int32 codes; None when `sdict` holds a value outside the domain."""
+        hit = self._remaps.get(id(sdict))
+        if hit is not None and hit[0]() is sdict:
+            return hit[1][codes]
+        remap = np.searchsorted(self.values, sdict).astype(np.int32)
+        if len(sdict) and (remap.max() >= len(self.values) or not
+                           np.array_equal(self.values[remap], sdict)):
+            return None
+        return remap[codes]
+
+
 @dataclasses.dataclass
 class Table:
     """A cached, partitioned, columnar table (shark.cache=true semantics)."""
@@ -339,6 +372,23 @@ class Table:
     # like any other — the mapping just lets `similarity_join` resolve a
     # logical vector column back to its lanes.
     embeddings: Dict[str, List[str]] = dataclasses.field(default_factory=dict)
+    # STRING column -> its StringDomain, built at first use (read-only
+    # partitions: a table's domains never go stale)
+    _domains: Dict[str, StringDomain] = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
+    _domain_lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False, compare=False)
+
+    def string_domain(self, column: str) -> "tuple[StringDomain, bool]":
+        """(the column's table-wide dictionary, whether it was cached)."""
+        with self._domain_lock:
+            domain = self._domains.get(column)
+            if domain is not None:
+                return domain, True
+            domain = StringDomain([p.columns[column].str_dict
+                                   for p in self.partitions])
+            self._domains[column] = domain
+            return domain, False
 
     @property
     def num_rows(self) -> int:
@@ -454,18 +504,31 @@ def from_arrays(name: str, schema: Schema, data: Dict[str, np.ndarray],
         pids = hash_partition_arrays(np.asarray(keyv), num_partitions)
         order = np.argsort(pids, kind="stable")
         bounds = np.searchsorted(pids[order], np.arange(num_partitions + 1))
-        parts = []
-        for i in range(num_partitions):
-            sel = order[bounds[i]: bounds[i + 1]]
-            parts.append(build_partition(
-                i, schema, {k: v[sel] for k, v in norm.items()}))
+        parts = _load_tasks(schema, norm, [
+            order[bounds[i]: bounds[i + 1]] for i in range(num_partitions)])
         return Table(name, schema, parts, distribute_key=distribute_by,
                      embeddings=embeddings)
     # round-robin contiguous split
     edges = np.linspace(0, n, num_partitions + 1, dtype=np.int64)
-    parts = []
-    for i in range(num_partitions):
-        lo, hi = int(edges[i]), int(edges[i + 1])
-        parts.append(build_partition(
-            i, schema, {k: v[lo:hi] for k, v in norm.items()}))
+    parts = _load_tasks(schema, norm, [
+        slice(int(edges[i]), int(edges[i + 1]))
+        for i in range(num_partitions)])
     return Table(name, schema, parts, embeddings=embeddings)
+
+
+def _load_tasks(schema: Schema, data: Dict[str, np.ndarray],
+                rows: Sequence) -> List[Partition]:
+    """One load task per partition (§3.3), run on a thread pool: each
+    builds its blocks from its own rows, and numpy's sorts and casts,
+    which dominate dictionary encoding, release the interpreter lock."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    def task(i: int) -> Partition:
+        return build_partition(i, schema,
+                               {k: v[rows[i]] for k, v in data.items()})
+
+    workers = max(1, min(len(rows), os.cpu_count() or 1, LOAD_THREADS))
+    if workers == 1:
+        return [task(i) for i in range(len(rows))]
+    with ThreadPoolExecutor(workers, thread_name_prefix="shark-load") as ex:
+        return list(ex.map(task, range(len(rows))))

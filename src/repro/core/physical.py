@@ -28,7 +28,8 @@ import numpy as np
 
 from . import tracing
 from .aggregate import (CompiledMerge, combine_colscan_stats, group_indices,
-                        merge_aggregate, partial_aggregate)
+                        merge_aggregate, partial_aggregate,
+                        sorted_partial_aggregate)
 from .batch import PartitionBatch
 from .catalog import Catalog
 from .columnar import Table
@@ -1542,7 +1543,7 @@ class Executor:
                 # reduce below, so semantics and row order match the
                 # single-host path by construction
                 mesh_partials = self._mesh_partials(src, runner, group_cols,
-                                                    aggs)
+                                                    aggs, scanc.table)
             if mesh_partials is not None:
                 map_rdd = self._prep_exchange(
                     self.ctx.parallelize(mesh_partials))
@@ -1577,8 +1578,7 @@ class Executor:
 
         dep = self._new_shuffle(
             map_rdd, num_buckets, partitioner,
-            accumulators=lambda: [SizeAccumulator(num_buckets)] + (
-                [HeavyHitterAccumulator(group_cols[0])] if group_cols else []))
+            accumulators=lambda: [SizeAccumulator(num_buckets)])
 
         if (not group_cols and self._fusion_mode != "off"
                 and self._pipeline_gate(dep)):
@@ -1644,40 +1644,53 @@ class Executor:
     # -- mesh-sharded map side (cluster tier, DESIGN.md §13.1) ----------------
 
     def _mesh_partials(self, src: RDD, runner: "SegmentRunner",
-                       group_cols, aggs) -> Optional[List[PartitionBatch]]:
+                       group_cols, aggs, table: Optional[Table]
+                       ) -> Optional[List[PartitionBatch]]:
         """Compute the aggregate's partial states on the device mesh.
 
         Eligibility is the kernel shape check the single-host routes use
-        (`_agg_kernel_shape`) narrowed to numeric columns; anything else
-        returns None and the host map side runs — a silent, always-correct
-        fallback.  The colscan shape shards (device × partition) with no
-        collective; the group-by shape runs the compiled radix exchange
-        across devices and partial-aggregates each device's received rows.
-        Either way the output is a list of partial-state batches that feed
-        the standard shuffle + merge, so the final rows (and their order)
-        are produced by exactly the single-host reduce path.
+        (`_agg_kernel_shape`) narrowed to what the mesh programs carry:
+        numeric filter and value lanes the accumulation dtype compares and
+        sums as the single-host kernels do, and integer or STRING group
+        keys.  A string key travels as table-wide int32 codes: the
+        partitions' dictionaries unified once per table and column
+        (`Table.string_domain`), so equal strings carry equal codes on
+        every device.  Anything else returns None and the host map side
+        runs — a silent, always-correct fallback.  The colscan shape shards
+        (device × partition) with no collective; the group-by shape runs
+        the compiled radix exchange across devices and partial-aggregates
+        each device's received rows.  Either way the output is a list of
+        partial-state batches that feed the standard shuffle + merge, so
+        the final rows are produced by exactly the single-host reduce path
+        (in the order its PDE bucket coalescing gives, which reads the map
+        outputs' sizes).
         """
         shape = runner._agg_kernel_shape(group_cols, aggs)
         if shape is None:
             return None
         from ..cluster import shard_exec
+        from ..kernels import ops as kernel_ops
         mesh = self.mesh
+        acc = kernel_ops.default_acc_dtype()
         before = mesh.retries
         batches = self.ctx.scheduler.run_result_stage(src)
         try:
             if shape[0] == "colscan":
                 _, fcol, lo, hi, vcol = shape
-                fvals, avals, int_sum = [], [], False
-                for b in batches:
-                    fv, vv = b.col(fcol), b.col(vcol)
-                    if fv.is_string or vv.is_string:
-                        return None
-                    varr = np.asarray(vv.arr)
-                    int_sum = int_sum or np.issubdtype(varr.dtype, np.integer)
-                    fvals.append(np.asarray(fv.arr, np.float64))
-                    avals.append(varr.astype(np.float64, copy=False))
-                stats, report = shard_exec.mesh_colscan(
-                    mesh, fvals, avals, float(lo), float(hi))
+                fv = [b.col(fcol) for b in batches]
+                vv = [b.col(vcol) for b in batches]
+                if any(v.is_string for v in fv + vv):
+                    return None
+                varrs = [np.asarray(v.arr) for v in vv]
+                int_sum = any(np.issubdtype(a.dtype, np.integer)
+                              for a in varrs)
+                got = shard_exec.mesh_colscan(
+                    mesh, shard_exec.filter_column(
+                        [np.asarray(v.arr) for v in fv], acc),
+                    varrs, lo, hi, acc)
+                if got is None:
+                    return None     # the lanes cannot compare it exactly
+                stats, report = got
                 out = []
                 for (cnt, s, mn, mx), b in zip(stats, batches):
                     out.append(runner._colscan_result(
@@ -1687,33 +1700,44 @@ class Executor:
                                  float(b.nbytes))
             else:                                   # ("groupby_mxu", g, v)
                 _, gsrc, vcol = shape
-                keys, vals = [], ([] if vcol is not None else None)
-                kdt = None
-                for b in batches:
-                    gv = b.col(gsrc)
-                    karr = np.asarray(gv.arr)
-                    if gv.is_string or not np.issubdtype(karr.dtype,
-                                                         np.integer):
+                gv = [b.col(gsrc) for b in batches]
+                kdt, domain = None, None
+                if any(v.is_string for v in gv):
+                    domain = self._string_domain(table, gsrc)
+                    if domain is None or not all(v.is_string for v in gv):
+                        return None
+                    codes = [np.asarray(v.arr) for v in gv]
+                    sdicts = [v.sdict for v in gv]
+                    keys = shard_exec.Column(
+                        "codes", tuple(codes) + tuple(sdicts),
+                        tuple(c.shape[0] for c in codes),
+                        lambda p: domain.codes(codes[p], sdicts[p]))
+                else:
+                    keys = [np.asarray(v.arr) for v in gv]
+                    if not all(np.issubdtype(k.dtype, np.integer)
+                               for k in keys):
                         return None     # exchange hashes integer key lanes
-                    kdt = karr.dtype
-                    keys.append(karr)
-                    if vcol is not None:
-                        vv = b.col(vcol)
-                        if vv.is_string:
-                            return None
-                        vals.append(np.asarray(vv.arr))
-                per_dev, report = shard_exec.mesh_group_exchange(
-                    mesh, keys, vals)
+                    kdt = keys[0].dtype
+                vals = None
+                if vcol is not None:
+                    vv = [b.col(vcol) for b in batches]
+                    if any(v.is_string for v in vv):
+                        return None
+                    vals = [np.asarray(v.arr) for v in vv]
+                got = shard_exec.mesh_group_exchange(mesh, keys, vals, acc)
+                if got is None:
+                    return None     # a key outside the table's domain
+                per_dev, report = got
                 self.metrics.mesh_shipped_rows += report["shipped_rows"]
                 out = []
                 for kd, vd in per_dev:
-                    cols = {group_cols[0]: ColumnVal(
-                        kd.astype(kdt, copy=False))}
-                    for a in aggs:
-                        if a.arg is not None:
-                            cols[a.arg.name] = ColumnVal(vd)
-                    pb = partial_aggregate(PartitionBatch(cols), group_cols,
-                                           aggs)
+                    key = (ColumnVal(kd, domain.values, True)
+                           if domain is not None
+                           else ColumnVal(kd.astype(kdt, copy=False)))
+                    # each device's rows come back sorted by key: its
+                    # partial states are reductions over runs
+                    pb = sorted_partial_aggregate(key, vd, group_cols[0],
+                                                  aggs)
                     runner._note("mesh-exchange", int(kd.shape[0]),
                                  pb.num_rows, float(kd.nbytes))
                     out.append(pb)
@@ -1723,6 +1747,18 @@ class Executor:
         self.metrics.mesh_devices = report["devices"]
         self.metrics.mesh_retries += mesh.retries - before
         return out
+
+    @staticmethod
+    def _string_domain(table: Optional[Table], column: str):
+        """The table-wide dictionary of a STRING column (built at its first
+        use, then reused), in a `shark.mesh.dict` span; None without a
+        catalog table behind the scan."""
+        if table is None:
+            return None
+        with tracing.span("mesh.dict", column=column) as sp:
+            domain, hit = table.string_domain(column)
+            sp.set(entries=len(domain.values), hit=hit)
+        return domain
 
     # -- joins ----------------------------------------------------------------
 

@@ -59,10 +59,11 @@ GRID = [
     "SELECT k, COUNT(*) AS c, SUM(v) AS sv FROM t GROUP BY k",
     "SELECT k, AVG(v) AS a FROM t GROUP BY k",
     "SELECT k32, SUM(i32) AS si FROM t GROUP BY k32",
-    # host-path fallbacks: multi-col predicate, string group key, string
-    # aggregate input, int64 SUM exactness, expression argument
-    "SELECT COUNT(*) AS c FROM t WHERE v > 5 AND x < 0",
+    # a string group key crosses devices as table-wide dictionary codes
     "SELECT s, COUNT(*) AS c FROM t GROUP BY s",
+    # host-path fallbacks: multi-col predicate, string aggregate input,
+    # int64 SUM exactness, expression argument
+    "SELECT COUNT(*) AS c FROM t WHERE v > 5 AND x < 0",
     "SELECT COUNT(DISTINCT s) AS d FROM t WHERE x > 0",
     "SELECT k, SUM(k) AS sk FROM t GROUP BY k",
     "SELECT SUM(v + 1.0) AS sv FROM t WHERE x > 0",
@@ -91,8 +92,8 @@ class TestMeshOracleGrid:
                 mesh_routed += routes.get("mesh-colscan", 0)
                 mesh_routed += routes.get("mesh-exchange", 0)
             # the grid must actually exercise the mesh, not fall back
-            # everywhere (7 eligible queries x >= 1 routed partition)
-            assert mesh_routed >= 7, mesh_routed
+            # everywhere (8 eligible queries x >= 1 routed partition)
+            assert mesh_routed >= 8, mesh_routed
         finally:
             on.shutdown()
             off.shutdown()
@@ -100,7 +101,7 @@ class TestMeshOracleGrid:
     def test_fallback_queries_take_host_routes(self):
         on = _session(MeshContext())
         try:
-            for q in GRID[7:]:
+            for q in GRID[8:]:
                 on.sql_np(q)
                 routes = on.metrics().segment_routes()
                 assert "mesh-colscan" not in routes, q
@@ -191,6 +192,70 @@ class TestMeshExchange:
         for d, (kd, vd) in enumerate(out):
             assert vd is None
             assert kd.shape[0] == int(counts[:, d].sum())
+
+
+class TestResidentLanes:
+    """Lanes of placed partitions kept on the devices between dispatches
+    (`MeshContext.resident`)."""
+
+    def test_hits_only_for_the_same_arrays_and_generation(self):
+        from repro.cluster.mesh import ResidentLanes
+        store = ResidentLanes(budget=None)
+        a, b = np.arange(4), np.arange(4)
+        assert store.keep("t", [a], 0, "A", 10) == 10
+        assert store.get("t", [a], 0) == "A"
+        assert store.get("t", [b], 0) is None          # equal, not the same
+        assert store.get("u", [a], 0) is None          # another lane
+        assert store.get("t", [a], 1) is None          # stale generation
+        assert len(store) == 0 and store.pinned == 0   # ... and dropped
+
+    def test_entry_goes_with_its_arrays(self):
+        from repro.cluster.mesh import ResidentLanes
+        store = ResidentLanes(budget=None)
+        a, keep = np.arange(4), np.arange(3)
+        store.keep("t", [a, keep], 0, "A", 10)
+        del a
+        assert len(store) == 0 and store.pinned == 0
+
+    def test_budget_evicts_least_recently_used(self):
+        from repro.cluster.mesh import ResidentLanes
+        store = ResidentLanes(budget=25)
+        xs = [np.arange(i + 1) for i in range(3)]
+        store.keep("t", [xs[0]], 0, 0, 10)
+        store.keep("t", [xs[1]], 0, 1, 10)
+        assert store.get("t", [xs[0]], 0) == 0         # xs[1] is now oldest
+        store.keep("t", [xs[2]], 0, 2, 10)
+        assert store.get("t", [xs[1]], 0) is None
+        assert store.get("t", [xs[0]], 0) == 0 and store.pinned == 20
+        assert store.keep("t", [xs[1]], 0, 1, 26) == 0  # never fits
+
+    def test_repeat_dispatch_ships_no_lanes(self):
+        from repro.core import tracing
+        rng = np.random.default_rng(8)
+        ctx = MeshContext()
+        keys = [rng.integers(0, 99, 300).astype(np.int64) for _ in range(5)]
+        vals = [rng.uniform(0, 1, 300) for _ in range(5)]
+        with tracing.recording():
+            first, _ = shard_exec.mesh_group_exchange(ctx, keys, vals)
+            again, _ = shard_exec.mesh_group_exchange(ctx, keys, vals)
+            spans = [r.attrs for r in tracing.records()
+                     if r.name == "shark.mesh"]
+        assert [s.get("fills", 0) for s in spans] == [2, 0]
+        assert spans[1]["h2d_bytes"] < spans[0]["h2d_bytes"] // 100
+        for (k1, v1), (k2, v2) in zip(first, again):
+            assert np.array_equal(k1, k2) and np.array_equal(v1, v2)
+        ctx.revive_all()                # nothing died: lanes stay
+        assert len(ctx.resident) == 2
+
+    def test_device_counts_are_checked_against_the_mirror(self, monkeypatch):
+        ctx = MeshContext()
+        keys = [np.arange(100, dtype=np.int64)]
+        real = shard_exec._mirror
+        monkeypatch.setattr(
+            shard_exec, "_mirror", lambda host, N, n_dev: real(
+                host, N, n_dev) + np.eye(n_dev, dtype=np.int64))
+        with pytest.raises(RuntimeError, match="host mirror"):
+            shard_exec.mesh_group_exchange(ctx, keys, None)
 
 
 @pytest.mark.multidevice

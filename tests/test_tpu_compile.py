@@ -91,3 +91,38 @@ def test_routed_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes, args, kw = CASES[name]
     compiled = _compile(one_chip, fn, shapes, *args, **kw)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the cluster tier's shard_map programs at the four-node Pavlo cell's
+# shapes (cluster/shard_exec.py): 16 partitions of 1.25M rows, 4 per chip,
+# padded to 2^21 rows; the exchange over 4 x 5M rows (4 x 2^21 lanes a
+# chip) with a 2^21-row stride; int32 lanes and float32 values, as the
+# chip runs them
+MESH_CASES = {
+    "mesh_colscan-4x4x2^21": ("colscan", (4, 1 << 21, "int32", "float32"),
+                              [((16, 1 << 21), I32), ((16, 1 << 21), F32),
+                               ((16,), I32), ((), I32), ((), I32)]),
+    "mesh_exchange-4x2^23": ("exchange", (4, 4, 1 << 21, 1 << 21, "int32",
+                                          "float32"),
+                             [((16, 1 << 21), I32), ((16,), I32),
+                              ((16, 1 << 21), F32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MESH_CASES))
+def test_mesh_program_compiles_for_v5e_2x2(topo, name):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.cluster import shard_exec
+    kind, static, shapes = MESH_CASES[name]
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    if kind == "colscan":
+        fn = shard_exec._colscan_program(mesh, *static)
+    else:
+        fn = shard_exec._exchange_program(mesh, *static)
+    sds = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(
+        mesh, P("data") if s else P())) for s, d in shapes]
+    text = fn.lower(*sds).compile().as_text()
+    assert "f64" not in text and "s64" not in text
+    if kind == "exchange":
+        assert "all-to-all" in text
