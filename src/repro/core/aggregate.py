@@ -425,6 +425,25 @@ class CompiledMerge:
         return PartitionBatch(out)
 
 
+def _final_column(spec: AggSpec, states: Sequence[np.ndarray]) -> ColumnVal:
+    """One aggregate's output column from its merged state columns (one
+    entry per group; AVG's are (sum, count)): COUNT as int64, SUM as its
+    state (int64 exact, float64 otherwise), AVG as sum / max(count, 1),
+    MIN and MAX as their state."""
+    if spec.func == AggFunc.COUNT:
+        return ColumnVal(np.round(states[0]).astype(np.int64))
+    if spec.func == AggFunc.AVG:
+        return ColumnVal(states[0] / np.maximum(states[1], 1.0))
+    if spec.func in (AggFunc.SUM, AggFunc.MIN, AggFunc.MAX):
+        return ColumnVal(states[0])
+    raise ExprCompileError(str(spec.func))
+
+
+# the [sum, count, min, max] lane each aggregate's states are read from
+_LANE_OF = {AggFunc.COUNT: 0, AggFunc.SUM: 0, AggFunc.AVG: 0,
+            AggFunc.MIN: 2, AggFunc.MAX: 3}
+
+
 def merge_from_lanes(batch: PartitionBatch, group_cols: Sequence[str],
                      aggs: Sequence[AggSpec], first: np.ndarray,
                      lanes: Dict[str, np.ndarray]) -> PartitionBatch:
@@ -434,20 +453,24 @@ def merge_from_lanes(batch: PartitionBatch, group_cols: Sequence[str],
     CompiledMerge) so the per-AggFunc output policy has one home."""
     out = _group_key_cols(batch, group_cols, first)
     for spec in aggs:
-        cols = _state_cols(spec)
-        if spec.func == AggFunc.COUNT:
-            out[spec.out_name] = ColumnVal(
-                np.round(lanes[cols[0]][:, 0]).astype(np.int64))
-        elif spec.func == AggFunc.SUM:
-            out[spec.out_name] = ColumnVal(lanes[cols[0]][:, 0])
-        elif spec.func == AggFunc.AVG:
-            s = lanes[cols[0]][:, 0]
-            c = lanes[cols[1]][:, 0]
-            out[spec.out_name] = ColumnVal(s / np.maximum(c, 1.0))
-        elif spec.func == AggFunc.MIN:
-            out[spec.out_name] = ColumnVal(lanes[cols[0]][:, 2])
-        elif spec.func == AggFunc.MAX:
-            out[spec.out_name] = ColumnVal(lanes[cols[0]][:, 3])
-        else:
+        lane = _LANE_OF.get(spec.func)
+        if lane is None:
             raise ExprCompileError(str(spec.func))
+        out[spec.out_name] = _final_column(
+            spec, [lanes[c][:, lane] for c in _state_cols(spec)])
+    return PartitionBatch(out)
+
+
+def finalize_disjoint(batch: PartitionBatch, group_cols: Sequence[str],
+                      aggs: Sequence[AggSpec]) -> PartitionBatch:
+    """The final rows of partial states that already hold one row per
+    group, for groups no other batch holds — the mesh exchange's
+    per-device output, where each key lives on one device.  Each state is
+    its group's merged state, so no grouping runs: the rows keep the
+    batch's order, and a batch sorted by key (as the exchange's are) gives
+    `merge_aggregate`'s rows and dtypes."""
+    out: Dict[str, ColumnVal] = {g: batch.col(g) for g in group_cols}
+    for spec in aggs:
+        out[spec.out_name] = _final_column(
+            spec, [np.asarray(batch.col(c).arr) for c in _state_cols(spec)])
     return PartitionBatch(out)

@@ -27,9 +27,9 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from . import tracing
-from .aggregate import (CompiledMerge, combine_colscan_stats, group_indices,
-                        merge_aggregate, partial_aggregate,
-                        sorted_partial_aggregate)
+from .aggregate import (CompiledMerge, combine_colscan_stats,
+                        finalize_disjoint, group_indices, merge_aggregate,
+                        partial_aggregate, sorted_partial_aggregate)
 from .batch import PartitionBatch
 from .catalog import Catalog
 from .columnar import Table
@@ -1054,6 +1054,18 @@ class ReduceRunner:
             sp.set(route=route, rows_out=out.num_rows)
         return out
 
+    def finalize(self, batch: PartitionBatch, group_cols: Sequence[str],
+                 aggs: Sequence[AggSpec]) -> PartitionBatch:
+        """The `disjoint` route: partial states whose keys no other batch
+        holds are final as they are (aggregate.finalize_disjoint)."""
+        with tracing.span("reduce", op="merge", route="disjoint",
+                          rows_in=batch.num_rows) as sp:
+            out = finalize_disjoint(batch, group_cols, aggs)
+            sp.set(rows_out=out.num_rows)
+        self._note("disjoint", batch.num_rows, out.num_rows,
+                   float(batch.nbytes))
+        return out
+
     def _merge_routed(self, batch: PartitionBatch,
                       group_cols: Sequence[str], aggs: Sequence[AggSpec]
                       ) -> Tuple[PartitionBatch, str]:
@@ -1539,14 +1551,19 @@ class Executor:
             mesh_partials = None
             if self.mesh is not None and self.backend == "compiled":
                 # cluster tier: run the map side sharded over the device
-                # mesh; the partial states feed the SAME shuffle/merge
-                # reduce below, so semantics and row order match the
-                # single-host path by construction
+                # mesh.  After the exchange each key lives on one device,
+                # so its per-device states are final; the colscan's
+                # partial states feed the SAME shuffle/merge reduce below
                 mesh_partials = self._mesh_partials(src, runner, group_cols,
                                                     aggs, scanc.table)
             if mesh_partials is not None:
-                map_rdd = self._prep_exchange(
-                    self.ctx.parallelize(mesh_partials))
+                partials, disjoint = mesh_partials
+                if disjoint:
+                    rrunner = self._reduce_runner("merge_aggregate", names)
+                    return Compiled(self.ctx.parallelize(
+                        [rrunner.finalize(b, group_cols, aggs)
+                         for b in partials]), names)
+                map_rdd = self._prep_exchange(self.ctx.parallelize(partials))
             elif self._fusion_mode != "off":
                 # whole-stage (DESIGN.md §14): the bucket layout is fixed
                 # BEFORE the map fn exists because radix bucketing runs
@@ -1645,8 +1662,9 @@ class Executor:
 
     def _mesh_partials(self, src: RDD, runner: "SegmentRunner",
                        group_cols, aggs, table: Optional[Table]
-                       ) -> Optional[List[PartitionBatch]]:
-        """Compute the aggregate's partial states on the device mesh.
+                       ) -> Optional[Tuple[List[PartitionBatch], bool]]:
+        """Compute the aggregate's partial states on the device mesh, and
+        say whether their keys are disjoint across batches.
 
         Eligibility is the kernel shape check the single-host routes use
         (`_agg_kernel_shape`) narrowed to what the mesh programs carry:
@@ -1657,13 +1675,15 @@ class Executor:
         (`Table.string_domain`), so equal strings carry equal codes on
         every device.  Anything else returns None and the host map side
         runs — a silent, always-correct fallback.  The colscan shape shards
-        (device × partition) with no collective; the group-by shape runs
-        the compiled radix exchange across devices and partial-aggregates
-        each device's received rows.  Either way the output is a list of
-        partial-state batches that feed the standard shuffle + merge, so
-        the final rows are produced by exactly the single-host reduce path
-        (in the order its PDE bucket coalescing gives, which reads the map
-        outputs' sizes).
+        (device × partition) with no collective and returns one partial
+        state per partition, which feed the standard single-bucket shuffle
+        + merge (disjoint False).  The group-by shape runs the compiled
+        radix exchange across devices, which sends every row to the one
+        device its key hashes to, and partial-aggregates each device's
+        received rows: one batch per device, in key order, whose keys no
+        other batch holds (disjoint True), so they need finalising but no
+        merge.  Device-loss retries stay inside the exchange, so the
+        survivors' batches are disjoint too.
         """
         shape = runner._agg_kernel_shape(group_cols, aggs)
         if shape is None:
@@ -1746,7 +1766,7 @@ class Executor:
         self.metrics.mesh_partitions += len(batches)
         self.metrics.mesh_devices = report["devices"]
         self.metrics.mesh_retries += mesh.retries - before
-        return out
+        return out, shape[0] != "colscan"
 
     @staticmethod
     def _string_domain(table: Optional[Table], column: str):

@@ -13,6 +13,7 @@ The cases that need several devices run in a subprocess started with
 (and count) on a host that exposes one device.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -108,6 +109,8 @@ def test_string_group_by_takes_the_mesh_exchange(check):
         assert not set(routes) & {"code-groupby", "whole-stage",
                                   "groupby_mxu", "jit"}, routes
         assert on.metrics().mesh_partitions == PARTS
+        # one device: its states are final, finalised in place
+        assert routes.get("disjoint") == 1 and "numpy" not in routes, routes
     finally:
         on.shutdown()
 
@@ -273,6 +276,60 @@ def test_sorted_partial_aggregate_is_partial_aggregate(funcs):
                 assert np.array_equal(a, b), c
 
 
+MERGE_MIXES = [("COUNT",), ("SUM",), ("AVG",), ("SUM", "COUNT"),
+               ("AVG", "COUNT", "SUM")]
+
+
+@pytest.mark.parametrize("vdt", [np.float32, np.int32])
+@pytest.mark.parametrize("funcs", MERGE_MIXES)
+def test_finalize_disjoint_is_merge_aggregate(funcs, vdt):
+    """Per-device states after the exchange (sorted, disjoint keys, one
+    device with no rows) finalise to merge_aggregate's rows and dtypes,
+    per device and over all devices together."""
+    from repro.core.aggregate import (finalize_disjoint, merge_aggregate,
+                                      sorted_partial_aggregate)
+    from repro.core.expr import Col
+    from repro.core.plan import AggFunc, AggSpec
+    rng = np.random.default_rng(len(funcs))
+    sdict = np.array([f"{i:03d}.1.2.3" for i in range(900)])
+    aggs = [AggSpec(f"a{i}", AggFunc[f],
+                    None if f == "COUNT" else Col("rev"))
+            for i, f in enumerate(funcs)]
+    codes = rng.integers(0, 700, 6000).astype(np.int32)
+    vals = rng.uniform(0, 1000, 6000).astype(vdt)
+    dev = codes % 4
+    dev[dev == 3] = 1                   # device 3 receives no rows
+    parts = []
+    for d in range(4):
+        o = np.argsort(codes[dev == d], kind="stable")
+        parts.append(sorted_partial_aggregate(
+            ColumnVal(codes[dev == d][o], sdict, True), vals[dev == d][o],
+            "ip", aggs))
+    assert parts[3].num_rows == 0
+    dtypes = None
+    for pb in parts + [PartitionBatch.concat(parts)]:
+        got = finalize_disjoint(pb, ["ip"], aggs)
+        want = merge_aggregate(pb, ["ip"], aggs)
+        assert got.names() == want.names() and got.num_rows == want.num_rows
+        if pb.num_rows == len(np.unique(codes)):
+            o = np.argsort(np.asarray(got.col("ip").arr))
+        else:
+            o = np.arange(got.num_rows)     # one device: already key order
+        for c in want.names():
+            a, b = got.col(c), want.col(c)
+            assert a.sdict is b.sdict and a.sorted_dict == b.sorted_dict
+            a, b = np.asarray(a.arr)[o], np.asarray(b.arr)
+            assert a.shape == b.shape and np.array_equal(a, b), c
+        if not pb.num_rows:
+            # merge_aggregate's np.bincount gives an empty float sum as
+            # int64; the empty device keeps the dtypes of the others
+            assert [got.col(c).arr.dtype for c in got.names()] == dtypes
+            continue
+        assert [got.col(c).arr.dtype for c in got.names()] == [
+            want.col(c).arr.dtype for c in want.names()]
+        dtypes = dtypes or [got.col(c).arr.dtype for c in got.names()]
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_merge_string_dicts_unifies_as_unique_and_searchsorted(seed):
     rng = np.random.default_rng(seed)
@@ -333,9 +390,14 @@ on, truth = t._session(mesh)
 off, _ = t._session(None)
 if case == "same_rows":
     out["routes"], out["shipped"], out["devices"] = [], 0, []
+    out["reduce"], out["rows"] = [], []
     with tracing.recording():
         for check, q in sorted(t.QUERIES.items()) + [("", t.RANGE_AGG)]:
+            n0 = len(tracing.records())
             got = on.sql_np(q)
+            out["reduce"].append([r.attrs for r in tracing.records()[n0:]
+                                  if r.name == "shark.reduce"])
+            out["rows"].append(len(next(iter(got.values()))))
             m = on.metrics()
             out["routes"].append(m.segment_routes())
             out["shipped"] += m.mesh_shipped_rows
@@ -366,12 +428,14 @@ elif case == "device_loss":
     out["spans"] = spans
     out["retries"] = mesh.retries
     out["devices"] = on.metrics().mesh_devices
+    out["routes"] = on.metrics().segment_routes()
 on.shutdown()
 off.shutdown()
 print("RESULT " + json.dumps(out, default=str))
 """
 
 
+@functools.lru_cache(maxsize=None)
 def _four_devices(case: str) -> dict:
     code = CHILD.format(repo=REPO, src=os.path.join(REPO, "src"),
                         tests=os.path.join(REPO, "tests"), case=case)
@@ -403,3 +467,40 @@ def test_four_devices_device_loss_mid_exchange_recomputes_identically():
     assert out["devices"] == 3
     (span,) = out["spans"]
     assert span["retries"] == 1 and span["devices"] == 3
+
+
+MERGE_ROUTES = {"numpy", "jit", "segmented_merge"}
+
+
+@pytest.mark.parametrize("check", sorted(QUERIES))
+def test_four_devices_group_by_finalises_each_device_in_place(check):
+    """After the exchange each key lives on one device: each device's
+    states are finalised where they are, one `disjoint` reduce span per
+    device and no shuffle or merge; the rows are checked in the child
+    against mesh-off and the reference."""
+    out = _four_devices("same_rows")
+    i = sorted(QUERIES).index(check)
+    routes = out["routes"][i]
+    assert routes.get("disjoint") == 4, routes
+    assert not set(routes) & MERGE_ROUTES, routes
+    spans = out["reduce"][i]
+    assert [s["route"] for s in spans] == ["disjoint"] * 4, spans
+    assert all(s["op"] == "merge" and s["rows_in"] == s["rows_out"]
+               for s in spans), spans
+    assert sum(s["rows_out"] for s in spans) == out["rows"][i]
+
+
+def test_four_devices_colscan_keeps_its_merge():
+    out = _four_devices("same_rows")
+    routes = out["routes"][2]
+    assert "disjoint" not in routes and set(routes) & MERGE_ROUTES, routes
+    (span,) = out["reduce"][2]
+    assert span["op"] == "merge" and span["route"] in MERGE_ROUTES
+    assert span["rows_in"] == PARTS and span["rows_out"] == 1
+
+
+def test_four_devices_device_loss_finalises_the_survivors():
+    out = _four_devices("device_loss")
+    routes = out["routes"]
+    assert routes.get("disjoint") == out["devices"] == 3, routes
+    assert not set(routes) & MERGE_ROUTES, routes
