@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache.
 
-Entry points (chip_smoke.py, benchmarks/run.py, examples/) call
+Entry points (chip_smoke.py, examples/) call
 `enable_compile_cache()` once before their first compile; importing the
 package sets nothing.  When `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it
 itself and this module sets nothing.  Otherwise the cache lives at a fixed

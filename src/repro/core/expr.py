@@ -215,12 +215,12 @@ def infer_dtype(e: Expr, schema: Schema) -> DType:
 # Materialization counters.  The dictionary-preserving exchange
 # (DESIGN.md §11) promises that shuffle/join/group paths never decode
 # string columns to raw values; every ColumnVal.decoded() of a string
-# column bumps string_cols/string_rows, so tests and
-# benchmarks/shuffle_bench.py can assert the promise (counter delta == 0
-# across execute()).  The encoded feature pipeline (DESIGN.md §15) makes
-# the same promise for numeric blocks: compression.decode_np bumps
-# numeric_blocks/numeric_rows on every host-side materialization of a
-# non-PLAIN block (memo misses only), so the encoded FeatureRDD train
+# column bumps string_cols/string_rows, so tests can assert the promise
+# (counter delta == 0 across execute()).  The encoded feature pipeline
+# (DESIGN.md §15) makes the same promise for numeric blocks:
+# compression.decode_np bumps numeric_blocks/numeric_rows on every
+# host-side materialization of a non-PLAIN block (memo misses only),
+# so the encoded FeatureRDD train
 # path can assert it hands DICT/FOR/BITPACK/RLE arrays to XLA without a
 # single host decode.  Plain dict mutation under the GIL — diagnostic
 # counters, not exact statistics.
@@ -836,9 +836,8 @@ class CompiledExprSet:
     chosen per partition (§3.2) — so every partition with the same layout
     reuses one compiled function."""
 
-    def __init__(self, exprs: Sequence[Expr], compressed_domain: bool = True):
+    def __init__(self, exprs: Sequence[Expr]):
         self.exprs = list(exprs)
-        self.compressed_domain = compressed_domain
         for e in self.exprs:
             if not _structurally_compilable(e):
                 raise ExprCompileError("string-transforming function in tree")
@@ -867,8 +866,7 @@ class CompiledExprSet:
             elif (name in self.code_candidates and v.block is not None
                     and v.block.code_space() is not None):
                 kinds[name] = "ndict"
-            elif (self.compressed_domain and name in self.code_candidates
-                    and v.block is not None
+            elif (name in self.code_candidates and v.block is not None
                     and v.block.frame_space() is not None):
                 # frame-of-reference codes: range predicates run on the
                 # narrow (value - bias) lane without widening (§12)
@@ -980,8 +978,8 @@ class CompiledExpr(CompiledExprSet):
     """`compile_expr(e)`: a one-expression CompiledExprSet returning the
     single ColumnVal directly."""
 
-    def __init__(self, expr: Expr, compressed_domain: bool = True):
-        super().__init__([expr], compressed_domain=compressed_domain)
+    def __init__(self, expr: Expr):
+        super().__init__([expr])
         self.expr = expr
 
     def __call__(self, ctx: Dict[str, ColumnVal]) -> ColumnVal:

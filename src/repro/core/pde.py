@@ -83,12 +83,6 @@ class PDEConfig:
     # means the overlap thread steals time from the maps (GIL + block-store
     # lock convoy), so the boundary falls back to the sequential pull fetch
     pipeline_reduce_slack_threads: int = 1
-    # -- compressed-domain execution (DESIGN.md §12) -------------------------
-    # evaluate range predicates on frame-of-reference codes and run-level
-    # predicates/aggregates on RLE runs without widening the column; off
-    # forces the decode-then-evaluate routes (differential tests drive the
-    # oracle grid both ways)
-    compressed_domain: bool = True
 
 
 @dataclasses.dataclass
@@ -389,8 +383,7 @@ def decide_train_backend(num_rows: int, dims: int,
         "jit", f"{num_rows}x{dims} partition -> fused assemble+train jit")
 
 
-def decide_stage_fusion(num_rows: int, mode: str = "on",
-                        backend: str = "compiled", exchange: str = "coded",
+def decide_stage_fusion(num_rows: int, backend: str = "compiled",
                         cfg: PDEConfig = PDEConfig()
                         ) -> SegmentBackendDecision:
     """Whole-stage fusion decision (DESIGN.md §14): should this partition's
@@ -399,22 +392,13 @@ def decide_stage_fusion(num_rows: int, mode: str = "on",
     stay on the segment-at-a-time path?
 
     Routes: "whole-stage" or "segment".  The fused program requires the
-    compiled backend and the dictionary-preserving exchange (the decoded
-    exchange re-materializes strings between the segment and the shuffle,
-    a host seam by definition); `mode="force"` bypasses the row threshold
-    (differential tests drive the oracle grid with it), `mode="off"` is
-    the semantic-oracle escape hatch."""
-    if mode == "off":
-        return SegmentBackendDecision("segment", "stage fusion disabled")
+    compiled backend (the numpy reference keeps every host seam); the row
+    threshold `stage_fusion_min_rows` is the one gate."""
     if backend != "compiled":
         return SegmentBackendDecision(
-            "segment", "numpy backend: the interpreted oracle keeps every "
-            "host seam")
-    if exchange != "coded":
-        return SegmentBackendDecision(
-            "segment", "decoded exchange re-materializes strings before "
-            "the shuffle: host seam required")
-    if mode != "force" and num_rows < cfg.stage_fusion_min_rows:
+            "segment", "numpy backend: the interpreted reference keeps "
+            "every host seam")
+    if num_rows < cfg.stage_fusion_min_rows:
         return SegmentBackendDecision(
             "segment", f"{num_rows} rows < {cfg.stage_fusion_min_rows} "
             "stage-fusion threshold")
@@ -423,7 +407,6 @@ def decide_stage_fusion(num_rows: int, mode: str = "on",
 
 
 def decide_pipelined_reduce(num_map_splits: int, max_threads: int,
-                            mode: str = "on",
                             cfg: PDEConfig = PDEConfig()
                             ) -> SegmentBackendDecision:
     """Should a single-bucket boundary start its reduce DURING the map stage
@@ -431,12 +414,7 @@ def decide_pipelined_reduce(num_map_splits: int, max_threads: int,
     as an extra runnable thread, so it only shortens the critical path when
     the executor pool has slots the map stage is not using — on a pool the
     map splits saturate, the thread can only steal time from the maps.
-    Routes: "pipelined" or "pull".  `mode="force"` bypasses the slack check
-    (the §14 chaos/differential tiers drive the overlap machinery
-    deterministically at any scale)."""
-    if mode == "force":
-        return SegmentBackendDecision(
-            "pipelined", "stage fusion forced -> overlapped reduce")
+    Routes: "pipelined" or "pull"."""
     slack = max_threads - num_map_splits
     if slack >= cfg.pipeline_reduce_slack_threads:
         return SegmentBackendDecision(

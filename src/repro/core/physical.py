@@ -474,8 +474,7 @@ class SegmentRunner:
             if not exprs:
                 return None
             try:
-                self._exprset = CompiledExprSet(
-                    exprs, compressed_domain=self.cfg.compressed_domain)
+                self._exprset = CompiledExprSet(exprs)
             except ExprCompileError:
                 self._exprset_failed = True
                 raise
@@ -603,15 +602,14 @@ class SegmentRunner:
                 codes, d = cs
                 kept.append(out_name)
                 return ColumnVal(d[codes[mask]])
-            if self.cfg.compressed_domain:
-                fs = v.block.frame_space()
-                if fs is not None:
-                    # FOR codes filtered narrow; only survivors widen
-                    codes, bias = fs
-                    kept.append(out_name)
-                    orig = v.block.enc.orig_dtype
-                    sel = codes[mask].astype(np.int64) + int(bias)
-                    return ColumnVal(sel.astype(orig))
+            fs = v.block.frame_space()
+            if fs is not None:
+                # FOR codes filtered narrow; only survivors widen
+                codes, bias = fs
+                kept.append(out_name)
+                orig = v.block.enc.orig_dtype
+                sel = codes[mask].astype(np.int64) + int(bias)
+                return ColumnVal(sel.astype(orig))
         return ColumnVal(np.asarray(v.arr)[mask])
 
     # -- fused aggregation -----------------------------------------------------
@@ -687,25 +685,20 @@ class SegmentRunner:
 
     def _aggregate_routed(self, batch: PartitionBatch,
                           group_cols: Sequence[str],
-                          aggs: Sequence[AggSpec], fused: bool = False,
-                          force_compiled: bool = False
+                          aggs: Sequence[AggSpec], fused: bool = False
                           ) -> Tuple[PartitionBatch, str]:
         """run_aggregate() returning (partial states, route) — the
         whole-stage wrapper (DESIGN.md §14) consumes the route to decide
-        whether the output ships pre-bucketed.  `force_compiled` upgrades a
-        small-partition numpy decision to the jit route (the differential
-        grid forces fusion on tiny seeds); empty partitions stay numpy —
-        jnp.min/max of a zero-length array is undefined."""
+        whether the output ships pre-bucketed."""
         with tracing.span("segment", rows_in=batch.num_rows) as sp:
             out, route = self._aggregate_by_route(batch, group_cols, aggs,
-                                                  fused, force_compiled)
+                                                  fused)
             sp.set(route=route, rows_out=out.num_rows)
         return out, route
 
     def _aggregate_by_route(self, batch: PartitionBatch,
                             group_cols: Sequence[str],
-                            aggs: Sequence[AggSpec], fused: bool,
-                            force_compiled: bool
+                            aggs: Sequence[AggSpec], fused: bool
                             ) -> Tuple[PartitionBatch, str]:
         rows = batch.num_rows
         nbytes = float(batch.nbytes)
@@ -724,8 +717,6 @@ class SegmentRunner:
             rows, shape[0] if shape is not None else None, ndv, _on_tpu(),
             self.cfg)
         route = decision.route
-        if route == "numpy" and force_compiled and rows > 0:
-            route = "jit"
         try:
             if route == "colscan":
                 out, route = self._run_colscan(batch, shape, aggs,
@@ -765,8 +756,7 @@ class SegmentRunner:
         from ..kernels import ops as kernel_ops
         _, fcol, lo, hi, vcol = shape
         fv = batch.col(fcol)
-        if (self.cfg.compressed_domain and not pallas
-                and fv.block is not None and not fv.materialized
+        if (not pallas and fv.block is not None and not fv.materialized
                 and not fv.is_string and fv.block.run_space() is not None):
             # run-level RLE scan: predicate on run VALUES, never widened
             return self._run_rle_scan(batch, fcol, lo, hi, vcol, aggs)
@@ -777,11 +767,9 @@ class SegmentRunner:
         if pallas and not coded and kernel_ops.colscan_filter(
                 fv.arr, acc) is None:
             pallas = False  # the kernel cannot compare this filter exactly
-        framed = (not coded and self.cfg.compressed_domain
-                  and fv.block is not None and not fv.materialized
+        framed = (not coded and fv.block is not None and not fv.materialized
                   and fv.block.frame_space() is not None)
         packed = (not coded and not framed and not pallas
-                  and self.cfg.compressed_domain
                   and fv.block is not None and not fv.materialized
                   and fv.block.pack_space() is not None)
         with _x64():
@@ -803,9 +791,7 @@ class SegmentRunner:
             elif coded:
                 # value bounds translate to CODE bounds host-side (sorted
                 # dictionary, same trick as expr._Lowering._dict_cmp): the
-                # scan compares int32 codes — no per-row dictionary gather,
-                # which is what made this route lose to numpy (the
-                # BENCH_exec_engine filter_agg_dict regression)
+                # scan compares int32 codes — no per-row dictionary gather
                 codes, d = fv.block.code_space()
                 clo = float(np.searchsorted(d, lo, side="left"))
                 chi = float(np.searchsorted(d, hi, side="right") - 1)
@@ -1277,15 +1263,11 @@ class ScanCache:
 
 class Executor:
     def __init__(self, ctx: SharkContext, catalog: Catalog,
-                 pde: PDEConfig = PDEConfig(), enable_pde: bool = True,
-                 enable_map_pruning: bool = True,
+                 pde: PDEConfig = PDEConfig(),
                  default_shuffle_buckets: int = 64,
                  scan_cache: Optional[ScanCache] = None,
-                 backend: str = "compiled", exchange: str = "coded",
-                 mesh=None, stage_fusion: str = "on"):
+                 backend: str = "compiled", mesh=None):
         assert backend in ("compiled", "numpy"), backend
-        assert exchange in ("coded", "decoded"), exchange
-        assert stage_fusion in ("on", "off", "force"), stage_fusion
         self.ctx = ctx
         self.catalog = catalog
         # cluster.MeshContext (DESIGN.md §13.1): when set, eligible
@@ -1294,30 +1276,20 @@ class Executor:
         # only — plans, explain() and fingerprints never see it.
         self.mesh = mesh
         self.pde = pde
-        self.enable_pde = enable_pde
-        self.enable_map_pruning = enable_map_pruning
         self.default_shuffle_buckets = default_shuffle_buckets
         self.scan_cache = scan_cache
         # "compiled": pipeline segments pick jit/Pallas routes per partition;
-        # "numpy": segments run the evaluate() oracle (differential testing)
+        # "numpy": segments run the evaluate() reference (differential
+        # testing).  String columns cross every shuffle as (codes,
+        # partition dictionary) and the reduce side merge-remaps
+        # dictionaries (DESIGN.md §11).
         self.backend = backend
-        # "coded": dictionary-preserving exchange — string columns cross
-        # shuffles as (codes, partition dictionary) and the reduce side
-        # merge-remaps dictionaries (DESIGN.md §11); "decoded": the legacy
-        # exchange that materializes raw strings before hashing, kept as
-        # the semantic oracle for differential tests and shuffle_bench
-        self.exchange = exchange
-        # whole-stage fusion (DESIGN.md §14): "on" fuses eligible map
-        # stages into one traced program ending in pre-bucketed shuffle
-        # output; "force" bypasses the PDE row threshold (test grids);
-        # "off" is the segment-at-a-time semantic oracle.  Fusion requires
-        # the compiled backend and the dictionary-preserving exchange —
-        # the decoded exchange's string re-materialization IS a host seam,
-        # and the numpy oracle must keep every seam — so it self-disables
-        # otherwise.
-        self._fusion_mode = (stage_fusion
-                             if backend == "compiled" and exchange == "coded"
-                             else "off")
+        # whole-stage fusion (DESIGN.md §14): on the compiled backend,
+        # eligible map stages may fuse into one traced program ending in
+        # pre-bucketed shuffle output; `decide_stage_fusion`'s row
+        # threshold gates each partition.  The numpy reference keeps every
+        # host seam.
+        self._fuse = backend == "compiled"
         # map-side radix bucketing through the Pallas kernel (TPU/forced);
         # fixed per executor so every map task of a shuffle agrees
         self._radix_kernel = (backend == "compiled"
@@ -1326,17 +1298,6 @@ class Executor:
         # outputs from the block store once the query completes
         self.created_shuffles: List[int] = []
         self.metrics = ExecMetrics()
-
-    def _prep_exchange(self, rdd: RDD) -> RDD:
-        """Map-side exchange prep.  The legacy ('decoded') exchange
-        materializes raw strings so the shuffle hashes raw values; the
-        dictionary-preserving exchange ships (codes, partition-local
-        dictionary) through the shuffle block untouched — hashing runs on
-        the dictionary (one crc32 per distinct value) and the reduce side
-        unifies dictionaries instead of decoding."""
-        if self.exchange == "decoded":
-            return rdd.map_partitions(lambda s, b: b.decode_strings())
-        return rdd
 
     def _reduce_runner(self, consumer: str, outputs: List[str]
                        ) -> ReduceRunner:
@@ -1417,7 +1378,7 @@ class Executor:
                       columns: Optional[Sequence[str]] = None) -> Compiled:
         table, version = self.catalog.get_versioned(node.table)
         selected = list(range(table.num_partitions))
-        if pred is not None and self.enable_map_pruning:
+        if pred is not None:
             kept = []
             for i in selected:
                 if may_match(pred, table.partitions[i].stats()):
@@ -1563,8 +1524,8 @@ class Executor:
                     return Compiled(self.ctx.parallelize(
                         [rrunner.finalize(b, group_cols, aggs)
                          for b in partials]), names)
-                map_rdd = self._prep_exchange(self.ctx.parallelize(partials))
-            elif self._fusion_mode != "off":
+                map_rdd = self.ctx.parallelize(partials)
+            elif self._fuse:
                 # whole-stage (DESIGN.md §14): the bucket layout is fixed
                 # BEFORE the map fn exists because radix bucketing runs
                 # inside the stage program — one traced call per partition
@@ -1573,13 +1534,13 @@ class Executor:
                     group_cols, src.num_partitions)
                 from .stage import StageRunner
                 stage = StageRunner(runner, partitioner, num_buckets,
-                                    self._fusion_mode, self.pde)
+                                    self.pde)
                 map_rdd = src.map_partitions(
                     lambda s, b: stage.run_aggregate_stage(b, group_cols,
                                                            aggs))
             else:
-                map_rdd = self._prep_exchange(src.map_partitions(
-                    lambda s, b: runner.run_aggregate(b, group_cols, aggs)))
+                map_rdd = src.map_partitions(
+                    lambda s, b: runner.run_aggregate(b, group_cols, aggs))
         else:
             child = self._materialize_empty(self._compile(node.child),
                                             node.child)
@@ -1587,7 +1548,7 @@ class Executor:
             def map_side(split: int, batch: PartitionBatch) -> PartitionBatch:
                 return partial_aggregate(batch, group_cols, aggs)
 
-            map_rdd = self._prep_exchange(child.rdd.map_partitions(map_side))
+            map_rdd = child.rdd.map_partitions(map_side)
 
         if partitioner is None:
             num_buckets, partitioner = self._bucket_layout(
@@ -1597,8 +1558,7 @@ class Executor:
             map_rdd, num_buckets, partitioner,
             accumulators=lambda: [SizeAccumulator(num_buckets)])
 
-        if (not group_cols and self._fusion_mode != "off"
-                and self._pipeline_gate(dep)):
+        if not group_cols and self._fuse and self._pipeline_gate(dep):
             # single-bucket boundary: no PDE re-planning consumes the map
             # stats, so the reduce can start as soon as pieces land —
             # pipelined map→reduce overlap (DESIGN.md §14)
@@ -1609,7 +1569,7 @@ class Executor:
         stats = self.ctx.scheduler.run_map_stage(dep)
         self.metrics.shuffled_bytes += stats.total_output_bytes()
 
-        if self.enable_pde and group_cols:
+        if group_cols:
             decision = decide_parallelism(stats, num_buckets, self.pde)
             self.metrics.reducer_decisions.append(decision.reason)
             groups = decision.bucket_groups
@@ -1637,8 +1597,7 @@ class Executor:
         map tasks; otherwise it takes the sequential pull fetch over the
         SAME shuffle blocks (the fused map side is unaffected)."""
         d = decide_pipelined_reduce(dep.parent.num_partitions,
-                                    self.ctx.scheduler.max_threads,
-                                    self._fusion_mode, self.pde)
+                                    self.ctx.scheduler.max_threads, self.pde)
         self.metrics.pipeline_decisions.append(d.reason)
         return d.route == "pipelined"
 
@@ -1825,27 +1784,25 @@ class Executor:
         of finished pieces, skipping the scheduler's host-assembly copy
         (DESIGN.md §14).  `partitioner` MUST be the same closure the
         ShuffleDependency carries, so fused and seam-by-seam pieces are
-        byte-identical.  Falls back to the legacy prep for interpreted /
-        non-segment sides and small partitions.
+        byte-identical.  Falls back to the plain map output for
+        interpreted / non-segment sides and small partitions.
 
         Bare unfiltered scans have no SegmentRunner (the PR-8 legacy-seam
         gap): synthesize a pass-through segment for them so their exchange
         buckets in-task too — observable as a `<table>->exchange-passthrough`
         record in ExecMetrics.segments."""
-        if self._fusion_mode == "off":
-            return self._prep_exchange(side.rdd)
+        if not self._fuse:
+            return side.rdd
         if side.runner is None:
             if side.table is None:
-                return self._prep_exchange(side.rdd)
+                return side.rdd
             side = dataclasses.replace(
                 side, runner=self._passthrough_runner(side.table))
         runner = side.runner
-        mode = self._fusion_mode
         cfg = self.pde
 
         def bucketize(split: int, batch: PartitionBatch):
-            d = decide_stage_fusion(batch.num_rows, mode, runner.backend,
-                                    "coded", cfg)
+            d = decide_stage_fusion(batch.num_rows, runner.backend, cfg)
             if d.route != "whole-stage":
                 return batch
             bucket_of = partitioner(batch)
@@ -1942,7 +1899,7 @@ class Executor:
             return self._broadcast(left, right, lkey, rkey, node.how,
                                    "planner-forced broadcast", names,
                                    broadcast_side="right")
-        if node.strategy == JoinStrategy.SHUFFLE or not self.enable_pde:
+        if node.strategy == JoinStrategy.SHUFFLE:
             return self._shuffle_join(left, right, lkey, rkey, node.how,
                                       names, note="planner-forced shuffle")
 
@@ -2033,8 +1990,7 @@ class Executor:
         skey, bkey = (rkey, lkey) if broadcast_side == "right" else (lkey, rkey)
         self.metrics.join_decisions.append(note)
         collected = PartitionBatch.concat(
-            self.ctx.scheduler.run_result_stage(
-                self._prep_exchange(small.rdd)))
+            self.ctx.scheduler.run_result_stage(small.rdd))
         self.metrics.broadcast_bytes += collected.nbytes
         observed = float(collected.nbytes)
         lb, rb = ((observed, big.size_hint or 0.0)
@@ -2095,12 +2051,12 @@ class Executor:
                     if limit is not None and len(keys) == 1 and keys[0][1]
                     else None)
 
-            if self._fusion_mode != "off":
+            if self._fuse:
                 # whole-stage (DESIGN.md §14): the sorted prefix ships as
                 # one zero-copy piece straight into the shuffle block
                 from .stage import StageRunner
-                stage = StageRunner(runner, single_bucket(), 1,
-                                    self._fusion_mode, self.pde, topk=topk)
+                stage = StageRunner(runner, single_bucket(), 1, self.pde,
+                                    topk=topk)
                 map_rdd = src.map_partitions(
                     lambda s, b: stage.run_sort_stage(b, keys, limit))
             else:
@@ -2112,8 +2068,7 @@ class Executor:
                         idx = idx[:limit]
                     return b.take(idx)
 
-                map_rdd = self._prep_exchange(
-                    src.map_partitions(seg_sort))
+                map_rdd = src.map_partitions(seg_sort)
             child = Compiled(map_rdd, names)
         else:
             child = self._materialize_empty(self._compile(node.child),
@@ -2126,8 +2081,7 @@ class Executor:
                 return batch.take(idx)
 
             # per-partition top-k, then single merge task (ORDER BY ... LIMIT)
-            map_rdd = self._prep_exchange(
-                child.rdd.map_partitions(local_sort))
+            map_rdd = child.rdd.map_partitions(local_sort)
         dep = self._new_shuffle(map_rdd, 1, single_bucket(),
                                 accumulators=lambda: [SizeAccumulator(1)])
 
@@ -2137,7 +2091,7 @@ class Executor:
                 idx = idx[:limit]
             return batch.take(idx)
 
-        if self._fusion_mode != "off" and self._pipeline_gate(dep):
+        if self._fuse and self._pipeline_gate(dep):
             pipe_fn = (lambda split, pieces:
                        final(split, PartitionBatch.concat(pieces)))
             _stats, pre = self.ctx.scheduler.run_map_stage_pipelined(
@@ -2159,33 +2113,29 @@ class Executor:
             # fused pushed-down limit: segment + head(n) in one step
             scanc, runner = self._make_runner(seg, "limit")
             src = self._segment_source_rdd(scanc, seg, ensure_nonempty=True)
-            if self._fusion_mode != "off":
+            if self._fuse:
                 # whole-stage (DESIGN.md §14): surviving columns ship
                 # encoded straight into the shuffle block as one zero-copy
                 # piece — the pass-through host-assembly seam fix
                 from .stage import StageRunner
-                stage = StageRunner(runner, single_bucket(), 1,
-                                    self._fusion_mode, self.pde)
+                stage = StageRunner(runner, single_bucket(), 1, self.pde)
                 head_rdd = src.map_partitions(
                     lambda s, b: stage.run_limit_stage(b, n))
             else:
                 head_rdd = src.map_partitions(
                     lambda s, b: runner.run(b).head(n))
             child = Compiled(head_rdd, seg.output_names(self.catalog))
-            prepped = (head_rdd if self._fusion_mode != "off"
-                       else self._prep_exchange(head_rdd))
         else:
             child = self._materialize_empty(self._compile(node.child),
                                             node.child)
 
             # §2.4: LIMIT pushed to partitions, final limit at collect
             head_rdd = child.rdd.map_partitions(lambda s, b: b.head(n))
-            prepped = self._prep_exchange(head_rdd)
 
         # wrap as a one-partition RDD via shuffle to a single bucket
-        dep = self._new_shuffle(prepped, 1, single_bucket())
+        dep = self._new_shuffle(head_rdd, 1, single_bucket())
         final = lambda s, b: b.head(n)
-        if self._fusion_mode != "off" and self._pipeline_gate(dep):
+        if self._fuse and self._pipeline_gate(dep):
             pipe_fn = (lambda split, pieces:
                        final(split, PartitionBatch.concat(pieces)))
             _stats, pre = self.ctx.scheduler.run_map_stage_pipelined(

@@ -50,7 +50,6 @@ from .types import Schema
 
 class SharkSession:
     def __init__(self, num_workers: int = 8, max_threads: int = 8,
-                 enable_pde: bool = True, enable_map_pruning: bool = True,
                  default_partitions: int = 8,
                  default_shuffle_buckets: int = 64,
                  pde_config: Optional[PDEConfig] = None,
@@ -58,8 +57,7 @@ class SharkSession:
                  task_launch_overhead_s: float = 0.0,
                  server=None, client_id: Optional[str] = None,
                  weight: float = 1.0, backend: str = "compiled",
-                 exchange: str = "coded", mesh=None,
-                 stage_fusion: str = "on", resilience=None):
+                 mesh=None, resilience=None):
         self.server = server
         if server is not None:
             # attached mode: share the server's runtime + catalog; queries
@@ -81,10 +79,8 @@ class SharkSession:
         self.default_partitions = default_partitions
         self.executor = Executor(
             self.ctx, self.catalog, pde_config or PDEConfig(),
-            enable_pde=enable_pde, enable_map_pruning=enable_map_pruning,
             default_shuffle_buckets=default_shuffle_buckets,
-            backend=backend, exchange=exchange, mesh=mesh,
-            stage_fusion=stage_fusion)
+            backend=backend, mesh=mesh)
 
     # -- data loading ---------------------------------------------------------
 

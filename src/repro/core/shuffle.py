@@ -90,35 +90,23 @@ def _kernel_buckets(k: np.ndarray, num_buckets: int) -> np.ndarray:
 
 def bucket_by_hash(key: str, num_buckets: int, kernel: bool = False
                    ) -> Callable[[PartitionBatch], np.ndarray]:
-    from .batch import EXCHANGE_TIMERS
-
     def partitioner(batch: PartitionBatch) -> np.ndarray:
-        import time
-        t0 = time.perf_counter()
         k = _row_keys(batch, key)
-        out = (_kernel_buckets(k, num_buckets) if kernel
-               else _mix_mod(k, num_buckets))
-        EXCHANGE_TIMERS["hash"] += time.perf_counter() - t0
-        return out
+        return (_kernel_buckets(k, num_buckets) if kernel
+                else _mix_mod(k, num_buckets))
     return partitioner
 
 
 def bucket_by_composite(keys: Sequence[str], num_buckets: int,
                         kernel: bool = False
                         ) -> Callable[[PartitionBatch], np.ndarray]:
-    from .batch import EXCHANGE_TIMERS
-
     def partitioner(batch: PartitionBatch) -> np.ndarray:
-        import time
-        t0 = time.perf_counter()
         h = np.zeros(batch.num_rows, np.int64)
         for key in keys:
             k = _row_keys(batch, key)
             h = h * np.int64(1000003) + k
-        out = (_kernel_buckets(h, num_buckets) if kernel
-               else _mix_mod(h, num_buckets))
-        EXCHANGE_TIMERS["hash"] += time.perf_counter() - t0
-        return out
+        return (_kernel_buckets(h, num_buckets) if kernel
+                else _mix_mod(h, num_buckets))
     return partitioner
 
 

@@ -15,12 +15,12 @@ returning to host between the compiled segment and the shuffle:
     produces, including under lineage recovery (tasks are deterministic);
   * sort/limit stages ship their single-reducer output as a zero-copy
     one-piece BucketedBatch — no host re-assembly copy for pass-through
-    columns (the BENCH_exec_engine "transfer-bound" seam).
+    columns.
 
 Fallback ladder (any rung keeps results identical):
-  1. PDE gate (`decide_stage_fusion`): numpy backend, decoded exchange,
-     `stage_fusion="off"`, or a partition under the row threshold → the
-     unfused segment-at-a-time path;
+  1. PDE gate (`decide_stage_fusion`): the numpy backend, or a partition
+     under the `stage_fusion_min_rows` threshold → the unfused
+     segment-at-a-time path;
   2. the routed segment itself picks the numpy oracle (tiny partition or
      ExprCompileError fallback) → the plain batch is returned and the
      scheduler applies the legacy partition/slice seam;
@@ -47,11 +47,10 @@ class StageRunner:
     """Fused map-stage driver wrapping one SegmentRunner (physical.py)."""
 
     def __init__(self, runner, partitioner: Callable, num_buckets: int,
-                 mode: str, cfg: PDEConfig, topk=None):
+                 cfg: PDEConfig, topk=None):
         self.runner = runner
         self.partitioner = partitioner
         self.num_buckets = num_buckets
-        self.mode = mode                     # "on" | "force"
         self.cfg = cfg
         # (lane columns, query weights) when the sort stage's key is a
         # dot-product similarity score (physical._match_topk): eligible
@@ -60,8 +59,7 @@ class StageRunner:
         self.topk = topk
 
     def _gate(self, num_rows: int) -> bool:
-        d = decide_stage_fusion(num_rows, self.mode, self.runner.backend,
-                                "coded", self.cfg)
+        d = decide_stage_fusion(num_rows, self.runner.backend, self.cfg)
         return d.route == "whole-stage"
 
     # -- aggregate stages ----------------------------------------------------
@@ -75,8 +73,7 @@ class StageRunner:
         if not self._gate(batch.num_rows):
             return self.runner.run_aggregate(batch, group_cols, aggs)
         out, route = self.runner._aggregate_routed(
-            batch, group_cols, aggs, fused=True,
-            force_compiled=(self.mode == "force"))
+            batch, group_cols, aggs, fused=True)
         if route == "numpy":
             return out          # oracle fallback: scheduler applies the seam
         bucket_of = self.partitioner(out)

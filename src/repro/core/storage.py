@@ -256,8 +256,7 @@ class StorageManager:
 
     `mode="spill"` is the real storage tier; `mode="drop"` releases cold
     partitions without writing anything (every fault recomputes from
-    lineage) — the eviction+recompute baseline `benchmarks/spill_bench.py`
-    measures against."""
+    lineage), the eviction+recompute baseline the spill tier replaces."""
 
     def __init__(self, spill_dir: Optional[str] = None, mode: str = "spill",
                  async_write: bool = True, policy=None):

@@ -311,7 +311,7 @@ class MemoryManager:
             "decode_cache_dropped_bytes": self.decode_cache_dropped_bytes,
             "chaos_pressure_drops": self.chaos_pressure_drops,
             # storage tier (zeros when no StorageManager is attached, so
-            # BENCH_concurrent.json always carries the keys)
+            # stats() always carries the keys)
             "spills": st.get("spills", 0),
             "spill_bytes": st.get("spill_bytes", 0),
             "spill_reads": st.get("spill_reads", 0),

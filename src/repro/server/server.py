@@ -58,17 +58,15 @@ class SharkServer:
                  max_queue_depth: int = 32,
                  enable_result_cache: bool = True,
                  result_cache_entries: int = 256,
-                 enable_pde: bool = True, enable_map_pruning: bool = True,
                  default_partitions: int = 8,
                  default_shuffle_buckets: int = 64,
                  pde_config: Optional[PDEConfig] = None,
                  speculation: bool = True,
                  task_launch_overhead_s: float = 0.0,
-                 backend: str = "compiled", exchange: str = "coded",
+                 backend: str = "compiled",
                  spill_dir: Optional[str] = None,
                  spill_mode: Optional[str] = None,
-                 mesh=None, stage_fusion: str = "on",
-                 resilience=None):
+                 mesh=None, resilience=None):
         self.ctx = SharkContext(num_workers=num_workers,
                                 max_threads=max_threads,
                                 speculation=speculation,
@@ -95,11 +93,9 @@ class SharkServer:
         self.catalog.subscribe(self._on_catalog_change)
         self.default_partitions = default_partitions
         self._exec_kw = dict(
-            pde=pde_config or PDEConfig(), enable_pde=enable_pde,
-            enable_map_pruning=enable_map_pruning,
+            pde=pde_config or PDEConfig(),
             default_shuffle_buckets=default_shuffle_buckets,
-            backend=backend, exchange=exchange, mesh=mesh,
-            stage_fusion=stage_fusion)
+            backend=backend, mesh=mesh)
         self.scheduler = FairScheduler(
             self._run_query, max_concurrent=max_concurrent_queries,
             max_queue_depth=max_queue_depth)

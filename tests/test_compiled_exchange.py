@@ -6,8 +6,8 @@ decode-free string shuffles (asserted via the expr.DECODE_COUNTERS row
 counter), the CompiledMerge / CompiledProbe jitted reduce kernels against
 their interpreted oracles, int64-exact aggregation above 2^53, the
 left-join string NULL fix, reduce-side route records in
-ExecMetrics.segments, plan-fingerprint/explain invariance across exchange
-modes, and (kernels_interpret-marked) the radix_partition and
+ExecMetrics.segments, row and plan-fingerprint/explain parity with the
+numpy-backend reference, and (kernels_interpret-marked) the radix_partition and
 segmented_merge Pallas kernels forced on CPU.
 """
 
@@ -320,8 +320,8 @@ def _data(seed=0, n=3000):
 SCHEMA = Schema.of(g=DType.STRING, v=DType.FLOAT64, k=DType.INT64)
 
 
-def _mk(exchange="coded", **kw):
-    sess = SharkSession(**SESSION_KW, exchange=exchange, **kw)
+def _mk(**kw):
+    sess = SharkSession(**SESSION_KW, **kw)
     sess.create_table("t", SCHEMA, _data())
     sess.create_table("d", Schema.of(dk=DType.INT64, ds=DType.STRING),
                       {"dk": np.arange(40, dtype=np.int64),
@@ -345,14 +345,15 @@ def test_coded_exchange_is_decode_free():
 
 
 def test_exchange_modes_agree_row_identically():
-    coded, decoded = _mk("coded"), _mk("decoded")
+    """The compiled exchange gives the numpy-backend reference's rows."""
+    comp, ref = _mk(), _mk(backend="numpy")
     queries = [
         "SELECT g, COUNT(*) AS c, SUM(v) AS s FROM t GROUP BY g",
         "SELECT ds, COUNT(*) AS c FROM t JOIN d ON t.k = d.dk GROUP BY ds",
         "SELECT g, v FROM t ORDER BY g, v LIMIT 25",
     ]
     for q in queries:
-        a, b = coded.sql_np(q), decoded.sql_np(q)
+        a, b = comp.sql_np(q), ref.sql_np(q)
         for col in a:
             av, bv = np.asarray(a[col]), np.asarray(b[col])
             oa = np.lexsort([np.asarray(a[c]).astype(str) for c in a])
@@ -361,26 +362,27 @@ def test_exchange_modes_agree_row_identically():
                 np.testing.assert_allclose(av[oa], bv[ob], rtol=1e-9)
             else:
                 assert av[oa].tolist() == bv[ob].tolist(), (q, col)
-    coded.shutdown()
-    decoded.shutdown()
+    comp.shutdown()
+    ref.shutdown()
 
 
 def test_exchange_mode_leaves_plans_untouched():
     """explain() and plan_fingerprint are functions of the logical plan;
-    the exchange is physical-layer only — byte-identical across modes."""
+    the exchange is physical-layer only — byte-identical between the
+    compiled backend and the numpy-backend reference."""
     from repro.core.plan import optimize
     from repro.server.result_cache import plan_fingerprint
-    coded, decoded = _mk("coded"), _mk("decoded")
+    comp, ref = _mk(), _mk(backend="numpy")
     q = ("SELECT ds, SUM(v) AS s FROM t JOIN d ON t.k = d.dk "
          "WHERE v > 1.5 GROUP BY ds ORDER BY s LIMIT 3")
-    assert coded.explain(q) == decoded.explain(q)
-    fp_c, _ = plan_fingerprint(optimize(coded.plan(q), coded.catalog),
-                               coded.catalog)
-    fp_d, _ = plan_fingerprint(optimize(decoded.plan(q), decoded.catalog),
-                               decoded.catalog)
-    assert fp_c == fp_d
-    coded.shutdown()
-    decoded.shutdown()
+    assert comp.explain(q) == ref.explain(q)
+    fp_c, _ = plan_fingerprint(optimize(comp.plan(q), comp.catalog),
+                               comp.catalog)
+    fp_r, _ = plan_fingerprint(optimize(ref.plan(q), ref.catalog),
+                               ref.catalog)
+    assert fp_c == fp_r
+    comp.shutdown()
+    ref.shutdown()
 
 
 def test_reduce_routes_recorded_in_metrics():

@@ -22,6 +22,7 @@ pd = pytest.importorskip("pandas")
 
 from repro.core import SharkSession
 
+from conftest import fusion_pde
 from oracle import QueryGen, compare, make_star_data, register_star_tables
 
 pytestmark = pytest.mark.tier1
@@ -144,37 +145,33 @@ N_EXCHANGE_SEEDS = 60
 
 @pytest.fixture(scope="module")
 def exchange_env(env):
-    """Two more executors over the SAME data: the compiled reduce path
-    FORCED ON over the dictionary-preserving exchange, and the legacy
-    decoded exchange with the numpy backend (compiled reduce forced off) —
-    the two extremes of the new exchange surface (DESIGN.md §11)."""
+    """One more executor over the SAME data: the compiled reduce path
+    FORCED ON over the dictionary-preserving exchange (DESIGN.md §11),
+    beside the numpy-backend reference session from `env`."""
     from repro.core.pde import PDEConfig
-    _, _, data, dfs, _ = env
-    sess_f = SharkSession(backend="compiled", exchange="coded",
+    _, sess_n, data, dfs, _ = env
+    sess_f = SharkSession(backend="compiled",
                           pde_config=PDEConfig(reduce_force_compiled=True),
                           **SESSION_KW)
-    sess_l = SharkSession(backend="numpy", exchange="decoded", **SESSION_KW)
     register_star_tables(sess_f, data)
-    register_star_tables(sess_l, data)
-    yield sess_f, sess_l, data, dfs
+    yield sess_f, sess_n, data, dfs
     sess_f.shutdown()
-    sess_l.shutdown()
 
 
 @pytest.mark.parametrize("seed", range(N_EXCHANGE_SEEDS))
 def test_compiled_reduce_forced_on_off_parity(exchange_env, seed):
-    """Row-identical parity between the forced compiled reduce path (coded
-    exchange) and the fully interpreted legacy path (decoded exchange,
-    numpy backend), both checked against pandas."""
-    sess_f, sess_l, data, dfs = exchange_env
+    """Row-identical parity between the forced compiled reduce path and
+    the fully interpreted numpy backend, both checked against pandas."""
+    sess_f, sess_n, data, dfs = exchange_env
     query = QueryGen(data, seed).gen()
     sql = query.sql()
     got_f = sess_f.sql_np(sql)
-    got_l = sess_l.sql_np(sql)
+    got_n = sess_n.sql_np(sql)
+    assert sess_n.metrics().compiled_partitions() == 0, sql
     ref = query.pandas(dfs)
     compare(query, got_f, ref)
-    compare(query, got_l, ref)
-    assert_backend_parity(query, got_f, got_l, sql)
+    compare(query, got_n, ref)
+    assert_backend_parity(query, got_f, got_n, sql)
     # the forced session must never take a numpy reduce route
     for s in sess_f.metrics().segments:
         if s.consumer in ("merge_aggregate", "join_probe"):
@@ -187,26 +184,22 @@ N_STORAGE_SEEDS = 40
 @pytest.fixture(scope="module")
 def storage_env(env):
     """Two more executors over the SAME data for the storage tier
-    (DESIGN.md §12): compressed-domain execution forced ON over adaptively
-    recompressed blocks (FOR/RLE layouts produced by the WARM-tier pass),
-    and forced OFF (every block decodes before the segment runs).  Wrong
+    (DESIGN.md §12), both over the same recompressed blocks (FOR/RLE
+    layouts produced by the WARM-tier pass): the compiled backend, whose
+    compressed-domain routes read those layouts, and the numpy-backend
+    reference, which decodes every block before it evaluates.  Wrong
     code-bound translation or run-level aggregation shows up here as a
-    parity break against pandas or against the decoded twin."""
-    from repro.core.pde import PDEConfig
+    parity break against pandas or against the reference."""
     _, _, data, dfs, _ = env
-    sess_on = SharkSession(backend="compiled",
-                           pde_config=PDEConfig(compressed_domain=True),
-                           **SESSION_KW)
-    sess_off = SharkSession(backend="compiled",
-                            pde_config=PDEConfig(compressed_domain=False),
-                            **SESSION_KW)
+    sess_on = SharkSession(backend="compiled", **SESSION_KW)
+    sess_off = SharkSession(backend="numpy", **SESSION_KW)
     register_star_tables(sess_on, data)
     register_star_tables(sess_off, data)
     # Force FOR / RLE layouts onto numeric columns (the star columns are
     # narrow-range, so adaptive recompression would pick BITPACK and the
     # grid would never touch the compressed-domain routes).  Predicates the
     # grid generates against these columns now hit the code-bound and
-    # run-level paths in the cd-on session.
+    # run-level paths in the compiled session.
     from repro.core.compression import Encoding, encode
     force = {"fact": {"fn": Encoding.FOR, "fk2": Encoding.FOR,
                       "fk3": Encoding.RLE},
@@ -227,8 +220,8 @@ def storage_env(env):
 @pytest.mark.parametrize("seed", range(N_STORAGE_SEEDS))
 def test_compressed_domain_forced_on_off_parity(storage_env, seed):
     """Row-identical parity between compressed-domain execution (range
-    predicates on FOR codes, run-level RLE scans) and decode-first
-    execution, both checked against pandas."""
+    predicates on FOR codes, run-level RLE scans) and the numpy-backend
+    reference on the same blocks, both checked against pandas."""
     sess_on, sess_off, data, dfs = storage_env
     query = QueryGen(data, seed).gen()
     sql = query.sql()
@@ -238,7 +231,8 @@ def test_compressed_domain_forced_on_off_parity(storage_env, seed):
     compare(query, got_on, ref)
     compare(query, got_off, ref)
     assert_backend_parity(query, got_on, got_off, sql)
-    # forced OFF must never take a compressed-domain route
+    # the reference must never take a compiled or compressed-domain route
+    assert sess_off.metrics().compiled_partitions() == 0, sql
     for s in sess_off.metrics().segments:
         assert s.routes.get("for-colscan", 0) == 0, s.describe()
         assert s.routes.get("rle-scan", 0) == 0, s.describe()
@@ -247,8 +241,8 @@ def test_compressed_domain_forced_on_off_parity(storage_env, seed):
 def test_compressed_domain_routes_fire_on_forced_layouts(storage_env):
     """The random grid rarely draws the exact colscan shape, so pin it:
     a range predicate over a FOR column and an RLE column must take the
-    code-bound / run-level routes when forced on, the decoded routes when
-    forced off, and agree either way."""
+    code-bound / run-level routes on the compiled backend, and agree with
+    the numpy-backend reference, which takes neither."""
     sess_on, sess_off, _, _ = storage_env
     cases = [
         ("SELECT COUNT(*) AS c, SUM(fv) AS s FROM fact "
@@ -292,15 +286,15 @@ N_FUSION_SEEDS = 60
 @pytest.fixture(scope="module")
 def fusion_env(env):
     """Three-way fusion differential over the SAME data: whole-stage
-    compilation FORCED (every eligible partition runs the fused stage
-    program), fusion OFF (the segment-at-a-time path with its host seams —
-    the semantic oracle for the fused path), and the fully interpreted
-    numpy backend from `env`.  All three must agree row-identically."""
+    compilation FUSED (every eligible partition runs the fused stage
+    program), UNFUSED (the compiled segment-at-a-time path with its host
+    seams), and the fully interpreted numpy backend from `env`.  All three
+    must agree row-identically."""
     _, sess_n, data, dfs, _ = env
-    sess_ws = SharkSession(backend="compiled", exchange="coded",
-                           stage_fusion="force", **SESSION_KW)
-    sess_seam = SharkSession(backend="compiled", exchange="coded",
-                             stage_fusion="off", **SESSION_KW)
+    sess_ws = SharkSession(backend="compiled", pde_config=fusion_pde(True),
+                           **SESSION_KW)
+    sess_seam = SharkSession(backend="compiled",
+                             pde_config=fusion_pde(False), **SESSION_KW)
     register_star_tables(sess_ws, data)
     register_star_tables(sess_seam, data)
     fusion_coverage = {}   # archetype -> fused (whole-stage) partitions
@@ -324,7 +318,7 @@ def _run_one_fused(fusion_env, seed):
     mseam = sess_seam.metrics()
     assert mseam.interpreted_scan_ops == 0, sql
     assert mseam.fused_partitions() == 0, \
-        f"stage_fusion='off' still fused a stage\n  {sql}"
+        f"the unfused session still fused a stage\n  {sql}"
     assert "whole-stage" not in mseam.segment_routes(), sql
     got_n = sess_n.sql_np(sql)
     assert sess_n.metrics().fused_partitions() == 0, sql
@@ -336,7 +330,7 @@ def _run_one_fused(fusion_env, seed):
 
 @pytest.mark.parametrize("seed", range(N_FUSION_SEEDS))
 def test_stage_fusion_forced_on_off_parity(fusion_env, seed):
-    """Whole-stage FORCED vs segment-at-a-time vs fully interpreted: all
+    """Whole-stage FUSED vs segment-at-a-time vs fully interpreted: all
     three row-identical to each other and to pandas."""
     _, _, _, _, dfs, _ = fusion_env
     query, sql, got_ws, got_seam, got_n = _run_one_fused(fusion_env, seed)

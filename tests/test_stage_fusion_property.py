@@ -15,6 +15,8 @@ from repro.core import DType, Schema, SharkSession
 from repro.core.plan import optimize
 from repro.server.result_cache import plan_fingerprint
 
+from conftest import fusion_pde
+
 pytestmark = pytest.mark.tier1
 
 AGGS = ("SUM", "AVG", "MIN", "MAX", "COUNT")
@@ -33,10 +35,10 @@ def sessions():
     schema = Schema.of(a=DType.INT64, b=DType.INT64, v=DType.FLOAT64,
                        s=DType.STRING)
     out = {}
-    for mode in ("off", "force"):
+    for mode in ("unfused", "fused"):
         sess = SharkSession(num_workers=2, max_threads=4,
                             default_partitions=3, default_shuffle_buckets=4,
-                            stage_fusion=mode)
+                            pde_config=fusion_pde(mode == "fused"))
         sess.create_table("t", schema, data)
         out[mode] = sess
     yield out
@@ -74,11 +76,11 @@ def _check_one(sessions, sql):
         node = optimize(sess.plan(sql), sess.catalog)
         fps[mode] = plan_fingerprint(node, sess.catalog)[0]
         results[mode] = sess.sql_np(sql)
-    assert plans["force"] == plans["off"], \
+    assert plans["fused"] == plans["unfused"], \
         f"fusion changed explain()\n  {sql}"
-    assert fps["force"] == fps["off"], \
+    assert fps["fused"] == fps["unfused"], \
         f"fusion changed plan_fingerprint\n  {sql}"
-    rows_f, rows_o = _rows(results["force"]), _rows(results["off"])
+    rows_f, rows_o = _rows(results["fused"]), _rows(results["unfused"])
     assert len(rows_f) == len(rows_o), sql
     for rf, ro in zip(rows_f, rows_o):
         for vf, vo in zip(rf, ro):
@@ -87,7 +89,7 @@ def _check_one(sessions, sql):
                     f"{vf!r} != {vo!r}\n  {sql}"
             else:
                 assert vf == vo, f"{vf!r} != {vo!r}\n  {sql}"
-    assert sessions["off"].metrics().fused_partitions() == 0
+    assert sessions["unfused"].metrics().fused_partitions() == 0
 
 
 def test_fusion_invariants_sweep(sessions):
@@ -104,7 +106,7 @@ def test_fusion_invariants_sweep(sessions):
     for pred_col, op, thr, shape, gcol, agg, acol, limit in cases:
         _check_one(sessions, _gen_sql(pred_col, op, thr, shape, gcol,
                                       agg, acol, limit or 7))
-    assert sessions["force"].metrics().fused_partitions() > 0
+    assert sessions["fused"].metrics().fused_partitions() > 0
 
 
 try:

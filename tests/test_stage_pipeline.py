@@ -13,8 +13,8 @@ seeded differential grid in test_oracle_differential.py:
     chunking) is bit-identical to single-shot dispatch
     (kernels_interpret-marked, runs on CPU in interpret mode);
   * fusion is physical-layer only: `explain()` text and the optimizer
-    `plan_fingerprint` are byte-identical with stage_fusion on / off /
-    force.
+    `plan_fingerprint` are byte-identical with the default, fused and
+    unfused configurations.
 """
 
 import numpy as np
@@ -23,6 +23,10 @@ import pytest
 from repro.core import DType, Schema, SharkSession, tracing
 from repro.core.pde import (PDEConfig, decide_pipelined_reduce,
                             decide_stage_fusion)
+from repro.core.physical import Executor
+from repro.server import SharkServer
+
+from conftest import fusion_pde
 
 pytestmark = pytest.mark.tier1
 
@@ -72,34 +76,34 @@ def _star_session(backend="compiled", pde_config=None, rows=3000,
 def test_stage_fusion_gate():
     cfg = PDEConfig()
     big = cfg.stage_fusion_min_rows
-    assert decide_stage_fusion(big, "on", "compiled", "coded",
-                               cfg).route == "whole-stage"
-    assert decide_stage_fusion(big, "off", "compiled", "coded",
+    assert decide_stage_fusion(big, "compiled", cfg).route == "whole-stage"
+    assert decide_stage_fusion(big, "numpy", cfg).route == "segment"
+    # the row threshold is the one gate on the compiled backend
+    assert decide_stage_fusion(big - 1, "compiled",
                                cfg).route == "segment"
-    assert decide_stage_fusion(big, "on", "numpy", "coded",
-                               cfg).route == "segment"
-    assert decide_stage_fusion(big, "on", "compiled", "decoded",
-                               cfg).route == "segment"
-    # row floor applies in "on" mode, not in "force"
-    assert decide_stage_fusion(big - 1, "on", "compiled", "coded",
-                               cfg).route == "segment"
-    assert decide_stage_fusion(big - 1, "force", "compiled", "coded",
-                               cfg).route == "whole-stage"
+    assert decide_stage_fusion(0, "compiled",
+                               fusion_pde(True)).route == "whole-stage"
+    # the exchange and fusion are not options: one production path
+    for opt in ("exchange", "stage_fusion", "enable_pde",
+                "enable_map_pruning"):
+        for make in (SharkSession, SharkServer,
+                     lambda **kw: Executor(None, None, **kw)):
+            with pytest.raises(TypeError):
+                make(**{opt: None})
 
 
 def test_pipelined_reduce_admission_gate():
     """The overlap thread is admitted only when the executor pool keeps a
-    slot free of map tasks; "force" mode bypasses the check."""
+    slot free of map tasks."""
     cfg = PDEConfig()
-    assert decide_pipelined_reduce(3, 4, "on", cfg).route == "pipelined"
+    assert decide_pipelined_reduce(3, 4, cfg).route == "pipelined"
     # map splits saturate (or exceed) the pool -> sequential pull fetch
-    assert decide_pipelined_reduce(4, 4, "on", cfg).route == "pull"
-    assert decide_pipelined_reduce(8, 4, "on", cfg).route == "pull"
-    assert decide_pipelined_reduce(8, 4, "force", cfg).route == "pipelined"
+    assert decide_pipelined_reduce(4, 4, cfg).route == "pull"
+    assert decide_pipelined_reduce(8, 4, cfg).route == "pull"
     # the slack requirement is a PDE knob
     wide = PDEConfig(pipeline_reduce_slack_threads=3)
-    assert decide_pipelined_reduce(3, 4, "on", wide).route == "pull"
-    assert decide_pipelined_reduce(1, 4, "on", wide).route == "pipelined"
+    assert decide_pipelined_reduce(3, 4, wide).route == "pull"
+    assert decide_pipelined_reduce(1, 4, wide).route == "pipelined"
 
 
 def test_pull_fallback_when_pool_is_saturated():
@@ -267,24 +271,27 @@ PLAN_SQLS = [
 def test_explain_and_fingerprint_identical_across_fusion_modes():
     from repro.core.plan import optimize
     from repro.server.result_cache import plan_fingerprint
-    sessions = {mode: _star_session(stage_fusion=mode)[0]
-                for mode in ("on", "off", "force")}
+    sessions = {"default": _star_session()[0],
+                "fused": _star_session(pde_config=fusion_pde(True))[0],
+                "unfused": _star_session(pde_config=fusion_pde(False))[0]}
     try:
         for sql in PLAN_SQLS:
             plans = {m: s.explain(sql) for m, s in sessions.items()}
-            assert plans["on"] == plans["off"] == plans["force"], sql
+            assert plans["default"] == plans["unfused"] == plans["fused"], \
+                sql
             fps = {m: plan_fingerprint(
                        optimize(s.plan(sql), s.catalog), s.catalog)[0]
                    for m, s in sessions.items()}
-            assert fps["on"] == fps["off"] == fps["force"], sql
+            assert fps["default"] == fps["unfused"] == fps["fused"], sql
             # and the plans actually execute identically
             got = {m: s.sql_np(sql) for m, s in sessions.items()}
-            for k in got["off"]:
-                np.testing.assert_array_equal(got["on"][k], got["off"][k])
-                np.testing.assert_array_equal(got["force"][k],
-                                              got["off"][k])
-        assert sessions["off"].metrics().fused_partitions() == 0
-        assert sessions["force"].metrics().fused_partitions() > 0
+            for k in got["unfused"]:
+                np.testing.assert_array_equal(got["default"][k],
+                                              got["unfused"][k])
+                np.testing.assert_array_equal(got["fused"][k],
+                                              got["unfused"][k])
+        assert sessions["unfused"].metrics().fused_partitions() == 0
+        assert sessions["fused"].metrics().fused_partitions() > 0
     finally:
         for s in sessions.values():
             s.shutdown()

@@ -14,7 +14,6 @@ from repro.core.catalog import ExternalSource
 from repro.core.columnar import build_partition, from_arrays
 from repro.core.compression import (Encoding, choose_recompression, decode_np,
                                     encode, recompress)
-from repro.core.pde import PDEConfig
 from repro.core.session import SharkSession
 from repro.core.storage import (SpillCorrupt, StorageManager,
                                 deserialize_batch, deserialize_partition,
@@ -440,7 +439,10 @@ class TestShuffleSpill:
 # ---------------------------------------------------------------------------
 
 
-def _for_session(cd: bool):
+def _for_session(backend: str):
+    """A table recompressed to FOR and RLE blocks: the compiled backend
+    reads them in the compressed domain, the numpy-backend reference
+    decodes every block before it evaluates."""
     rng = np.random.default_rng(33)
     n = 40_000
     data = {"k": rng.integers(5 * 10**6, 5 * 10**6 + (1 << 20),
@@ -451,7 +453,7 @@ def _for_session(cd: bool):
     schema = Schema([Field("k", DType.INT64), Field("r", DType.INT32),
                      Field("v", DType.FLOAT64)])
     sess = SharkSession(num_workers=2, max_threads=4, default_partitions=4,
-                        pde_config=PDEConfig(compressed_domain=cd))
+                        backend=backend)
     sess.create_table("t", schema, data)
     for part in sess.catalog.get("t").partitions:
         for blk in part._columns.values():
@@ -465,7 +467,7 @@ def _for_session(cd: bool):
 
 class TestCompressedDomainRoutes:
     def test_for_colscan_route_and_parity(self):
-        on, off = _for_session(True), _for_session(False)
+        on, off = _for_session("compiled"), _for_session("numpy")
         q = ("SELECT COUNT(*) AS c, SUM(v) AS s, MIN(v) AS mn FROM t "
              "WHERE k BETWEEN 5200000 AND 5700000")
         r_on, r_off = on.sql_np(q), off.sql_np(q)
@@ -475,7 +477,7 @@ class TestCompressedDomainRoutes:
             np.testing.assert_allclose(r_on[k], r_off[k], rtol=1e-12)
 
     def test_rle_scan_route_and_parity(self):
-        on, off = _for_session(True), _for_session(False)
+        on, off = _for_session("compiled"), _for_session("numpy")
         for q in ("SELECT COUNT(*) AS c, SUM(r) AS s, MAX(r) AS mx FROM t "
                   "WHERE r BETWEEN 5 AND 25",
                   "SELECT COUNT(*) AS c, SUM(v) AS s FROM t "
@@ -490,7 +492,7 @@ class TestCompressedDomainRoutes:
         # small-range ints BITPACK-encode at load; the jit colscan must
         # compare biased codes on the packed lanes (host-translated bounds)
         # instead of widening the filter column
-        def _bp_session(cd: bool):
+        def _bp_session(backend: str):
             rng = np.random.default_rng(7)
             n = 40_000
             data = {"b": rng.integers(-50, 50, n).astype(np.int64),
@@ -498,8 +500,7 @@ class TestCompressedDomainRoutes:
             schema = Schema([Field("b", DType.INT64),
                              Field("v", DType.FLOAT64)])
             sess = SharkSession(num_workers=2, max_threads=4,
-                                default_partitions=4,
-                                pde_config=PDEConfig(compressed_domain=cd))
+                                default_partitions=4, backend=backend)
             sess.create_table("t", schema, data)
             encs = {nm: blk.enc.encoding
                     for p in sess.catalog.get("t").partitions
@@ -507,7 +508,7 @@ class TestCompressedDomainRoutes:
             assert encs["b"] == Encoding.BITPACK
             return sess
 
-        on, off = _bp_session(True), _bp_session(False)
+        on, off = _bp_session("compiled"), _bp_session("numpy")
         for q in ("SELECT COUNT(*) AS c, SUM(v) AS s, MIN(v) AS mn, "
                   "MAX(v) AS mx FROM t WHERE b BETWEEN -30 AND 20",
                   "SELECT COUNT(*) AS c, SUM(v) AS s FROM t WHERE b >= 44"):
@@ -518,14 +519,14 @@ class TestCompressedDomainRoutes:
                 np.testing.assert_allclose(r_on[k], r_off[k], rtol=1e-12)
 
     def test_for_filter_projection_parity(self):
-        on, off = _for_session(True), _for_session(False)
+        on, off = _for_session("compiled"), _for_session("numpy")
         q = "SELECT k, v FROM t WHERE k > 5900000 ORDER BY k"
         r_on, r_off = on.sql_np(q), off.sql_np(q)
         for k in r_on:
             np.testing.assert_array_equal(r_on[k], r_off[k])
 
     def test_explain_identical_on_off(self):
-        on, off = _for_session(True), _for_session(False)
+        on, off = _for_session("compiled"), _for_session("numpy")
         for q in ["SELECT COUNT(*) AS c FROM t WHERE k BETWEEN 5200000 "
                   "AND 5700000",
                   "SELECT k, v FROM t WHERE k > 5900000 ORDER BY k"]:

@@ -205,6 +205,6 @@ def _colscan_runner():
     runner.seg = seg
     runner.schema = schema
     runner.backend = "compiled"
-    runner.cfg = PDEConfig(compressed_domain=True)
+    runner.cfg = PDEConfig()
     runner.record = record
     return runner
